@@ -36,6 +36,9 @@ def main() -> None:
     args = ap.parse_args()
     steps = 60 if args.quick else 150
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from benchmarks import (table1_attack_grid, fig2_detection, fig2_reset,
                             convex_attack, saddle_escape, overhead,
                             campaign_throughput, bench_kernels, roofline,

@@ -61,6 +61,7 @@ from repro.campaign.scenario import (ADAPTIVE_ATTACKS, HETERO_DEFENSES,
                                      TABLE1_DEFENSES, ZOO_DEFENSES,
                                      expand_grid, scenario_id, with_seeds)
 from repro.campaign.store import DEFAULT_ROOT, CampaignStore
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def _table1(seeds: int, steps: int) -> List[Scenario]:
@@ -224,6 +225,7 @@ def main(argv=None) -> Dict:
                     help="echo each heartbeat as a per-cell progress "
                          "line (implies --tap-every 50 if unset)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     steps = args.steps if args.steps is not None else (40 if args.quick
                                                        else 150)

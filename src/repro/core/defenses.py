@@ -557,7 +557,7 @@ def _bucketing_static_nbyz_placeholder(name: str) -> Defense:
 
 def make_registry(m: int, n_byz, *, T0: int = 20, T1: int = 120,
                   threshold_floor=0.1, reset_period: int = 0,
-                  use_sketch: bool = False,
+                  use_sketch: bool = False, backend: str = "pallas",
                   clip_tau=DEFENSE_DEFAULTS["clip_tau"],
                   clip_beta=DEFENSE_DEFAULTS["clip_beta"],
                   spectral_iters=DEFENSE_DEFAULTS["spectral_iters"],
@@ -572,6 +572,8 @@ def make_registry(m: int, n_byz, *, T0: int = 20, T1: int = 120,
     and — for the non-``static_nbyz`` entries — ``n_byz`` may be traced
     scalars (campaign vmap knobs): registry construction never calls a
     defense, and the knobs only feed arithmetic inside ``aggregate``.
+    ``backend`` is the safeguard's flat distance pass (``"xla"`` under a
+    sharded mesh, see :class:`core.safeguard.SafeguardConfig`).
     """
     trim = derive_trim(n_byz, m)
 
@@ -580,7 +582,7 @@ def make_registry(m: int, n_byz, *, T0: int = 20, T1: int = 120,
                                   threshold_floor=threshold_floor,
                                   threshold_scale=threshold_scale,
                                   reset_period=reset_period,
-                                  use_sketch=use_sketch)
+                                  use_sketch=use_sketch, backend=backend)
 
     reg = {
         "mean": _stateless("mean", agg_lib.mean),

@@ -30,7 +30,7 @@ Three state representations are provided (DESIGN.md §6):
     place), and the pairwise-distance pass runs on the whole buffer at
     once — the ``safeguard_filter`` Pallas Gram kernel
     (``backend="pallas"``, interpret mode on CPU with the package's
-    ``ref.py`` as numerics oracle), a single XLA ``dot_general``
+    ``ref.py`` as numerics oracle), the oracle's fused f32 multiply-reduce
     (``backend="xla"``, the choice under a sharded mesh, DESIGN.md §3), or
     the fully fused accumulate+distance kernel streaming each d-tile
     through VMEM exactly once (``backend="pallas_fused"``, the TPU hot
@@ -69,7 +69,8 @@ def _on_tpu() -> bool:
 # --------------------------------------------------------------------------
 
 _LANE = 128           # TPU lane multiple (feature axis)
-_BLOCK_D = 512        # preferred d-tile of the Pallas kernel
+_BLOCK_D = 512        # smallest d-tile of the Pallas kernel
+_MAX_TILE = 32768     # largest d-tile the layout pads for
 
 
 @dataclasses.dataclass(frozen=True)
@@ -90,10 +91,23 @@ class FlatLayout:
     d_padded: int                     # d rounded up to a kernel-tile multiple
 
 
+def _pad_multiple(d: int) -> int:
+    """Feature-axis padding multiple: the lane width below one tile, else
+    the largest power-of-two d-tile up to ``_MAX_TILE`` that costs at most
+    ``d / 4096`` zero columns.  Large tiles keep the kernel's grid short:
+    fewer grid steps and fewer per-tile f32 partial Grams to sum."""
+    if d < _BLOCK_D:
+        return _LANE
+    tile = _BLOCK_D
+    while 2 * tile <= min(_MAX_TILE, d // 4096):
+        tile *= 2
+    return tile
+
+
 def make_layout(grads_like) -> FlatLayout:
     """``grads_like``: a parameter pytree (NOT worker-stacked).  The feature
-    axis is padded to the Pallas tile multiple (zeros never change
-    distances), so every downstream op is MXU-aligned with no per-step
+    axis is padded to a kernel-tile multiple (zeros never change
+    distances), so every downstream op is tile-aligned with no per-step
     re-padding."""
     leaves, treedef = jax.tree_util.tree_flatten(grads_like)
     if not leaves:
@@ -110,8 +124,7 @@ def make_layout(grads_like) -> FlatLayout:
         sizes.append(size)
         off += size
     d = off
-    pad_to = _BLOCK_D if d >= _BLOCK_D else _LANE
-    d_padded = d + (-d) % pad_to
+    d_padded = d + (-d) % _pad_multiple(d)
     return FlatLayout(treedef=treedef, shapes=tuple(shapes),
                       dtypes=tuple(dtypes), offsets=tuple(offsets),
                       sizes=tuple(sizes), d=d, d_padded=d_padded)
@@ -166,8 +179,9 @@ class SafeguardConfig:
         Gram kernel (interpret mode off-TPU);
       * ``"pallas_fused"`` — single streamed accumulate+distance kernel
         (flattens the gradients to one matrix per step; the TPU hot path);
-        requires f32 accumulators, else falls back to ``"xla"``;
-      * ``"xla"`` — in-place scatter accumulate + one XLA ``dot_general``;
+        requires f32 accumulators;
+      * ``"xla"`` — in-place scatter accumulate + the oracle's fused f32
+        multiply-reduce (``kernels/safeguard_filter/ref.py``);
         use under a sharded mesh where a single-device kernel cannot be
         partitioned (DESIGN.md §3).
     """
@@ -208,6 +222,10 @@ class SafeguardConfig:
             raise ValueError(f"bad engine {self.engine!r}")
         if self.backend not in ("pallas", "pallas_fused", "xla"):
             raise ValueError(f"bad backend {self.backend!r}")
+        if (self.backend == "pallas_fused"
+                and jnp.dtype(self.acc_dtype) != jnp.float32):
+            raise ValueError("backend 'pallas_fused' needs float32 "
+                             f"accumulators, got {jnp.dtype(self.acc_dtype)}")
         if self.T0 > self.T1:
             raise ValueError("need T0 <= T1")
         if self.rule == "theoretical" and self.thresh0 <= 0:
@@ -256,15 +274,16 @@ jax.tree_util.register_dataclass(
 def init_state(cfg: SafeguardConfig, grads_like) -> SafeguardState:
     """``grads_like``: a parameter pytree (NOT stacked) used for shapes."""
     layout = None
-    if cfg.use_sketch:
-        acc = jnp.zeros((cfg.m, cfg.sketch_reps * cfg.sketch_k), jnp.float32)
-        A = acc if cfg.mode == "double" else None
-        B = acc
-    elif cfg.engine == "flat":
-        layout = make_layout(grads_like)
-        acc = jnp.zeros((cfg.m, layout.d_padded), cfg.acc_dtype)
-        A = acc if cfg.mode == "double" else None
-        B = acc
+    # A and B are always distinct buffers: a step that donates its state
+    # may not receive one buffer twice
+    if cfg.use_sketch or cfg.engine == "flat":
+        if cfg.use_sketch:
+            shape, dtype = (cfg.m, cfg.sketch_reps * cfg.sketch_k), jnp.float32
+        else:
+            layout = make_layout(grads_like)
+            shape, dtype = (cfg.m, layout.d_padded), cfg.acc_dtype
+        A = jnp.zeros(shape, dtype) if cfg.mode == "double" else None
+        B = jnp.zeros(shape, dtype)
     else:
         def stacked(leaf):
             return jnp.zeros((cfg.m,) + leaf.shape, cfg.acc_dtype)
@@ -358,9 +377,9 @@ def _accumulate_flat(acc, grads, reset, scale, layout: FlatLayout):
 
 def _flat_sqdist(buf, cfg: SafeguardConfig):
     """Pairwise squared distances of the flat accumulator: blocked Pallas
-    Gram kernel (one block under the CPU interpreter) or a single XLA
-    ``dot_general`` (shardable: worker rows stay on their data shards and
-    only the (m, m) output is combined)."""
+    Gram kernel (one block under the CPU interpreter) or the oracle's XLA
+    multiply-reduce (shardable by XLA; on a ``(data=4, model=1)`` v5e mesh
+    it all-gathers the worker rows)."""
     if cfg.backend == "pallas":
         from repro.kernels.safeguard_filter import pairwise_sqdist
         return pairwise_sqdist(buf, block_d=None, interpret=not _on_tpu())
@@ -437,9 +456,8 @@ def safeguard_step(state: SafeguardState, grads, cfg: SafeguardConfig,
         sqdist_A = sk.sketch_pairwise_sqdist(A) if A is not None else None
     elif cfg.engine == "flat":
         layout = state.layout
-        use_fused = (cfg.backend == "pallas_fused"
-                     and jnp.dtype(cfg.acc_dtype) == jnp.float32)
-        gflat = flatten_stacked(grads, layout) if use_fused else None
+        gflat = (flatten_stacked(grads, layout)
+                 if cfg.backend == "pallas_fused" else None)
         B, sqdist_B = _flat_update(state.B, grads, gflat, reset_B,
                                    inv_ngood, cfg, layout)
         A, sqdist_A = None, None

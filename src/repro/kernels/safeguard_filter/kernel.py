@@ -7,9 +7,9 @@ Distances reduce to the Gram matrix, which is a rank-d update streamed
 through VMEM:
 
     grid over d-tiles; each step loads an (m, bd) tile of the flat
-    accumulator (HBM -> VMEM), issues one (m x bd) @ (bd x m)^T MXU
-    matmul, and accumulates into an f32 (m, m) VMEM scratch; the final
-    step expands the diagonal to emit squared distances.
+    accumulator (HBM -> VMEM), forms its (m, m) Gram from exact f32
+    products and writes it out; the per-tile Grams are summed and the
+    diagonal expanded to squared distances outside the kernel.
 
 Two entry points:
 
@@ -17,12 +17,12 @@ Two entry points:
   * ``fused_accumulate_sqdist_kernel`` — the safeguard hot path: each
     d-tile additionally applies the windowed accumulate-and-reset update
     ``acc <- [reset ? 0 : acc] + g / n_good`` *in place*
-    (``input_output_aliases``) before feeding the MXU, so the O(m d)
+    (``input_output_aliases``) before forming its Gram, so the O(m d)
     state is streamed exactly once per step.
 
-m is padded to the sublane multiple by ``ops.py`` / the flat layout;
+Every block spans all m worker rows (no sublane padding, see ``ops.py``);
 ``block_d`` is a multiple of the 128-wide lane dimension so each tile is
-MXU-aligned.
+lane-aligned.
 """
 
 from __future__ import annotations
@@ -35,24 +35,31 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _gram_kernel(a_ref, out_ref, acc_ref, *, nd: int):
-    i = pl.program_id(0)
+def _gram_tile(a):
+    """(m, bd) f32 -> (m, m) f32 ``a @ a.T`` from exact f32 products and
+    f32 lane sums, one column per worker — the oracle's arithmetic, with
+    no dependence on the MXU's f32 pass configuration.  At m = 4 a tile
+    costs the same as an MXU dot (both are bound by its load)."""
+    cols = [jnp.sum(a * a[i:i + 1, :], axis=1, keepdims=True)
+            for i in range(a.shape[0])]
+    return jnp.concatenate(cols, axis=1)
 
-    @pl.when(i == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    a = a_ref[...].astype(jnp.float32)                 # (m, bd)
-    acc_ref[...] += jax.lax.dot_general(
-        a, a, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)            # (m, m)
+def _sqdist_from_tile_grams(partial):
+    """(nd, m, m) per-tile Gram partials -> (m, m) f32 squared distances.
 
-    @pl.when(i == nd - 1)
-    def _finish():
-        g = acc_ref[...]
-        diag = jnp.diagonal(g)
-        sq = diag[:, None] + diag[None, :] - 2.0 * g
-        out_ref[...] = jnp.maximum(sq, 0.0)
+    The partials are summed here, by XLA's tree reduction, and not into
+    one VMEM accumulator inside the kernel: a running f32 sum over the
+    ~4e5 tiles of a 2.2e8-wide buffer drifted by 1.4e-3 of
+    ``|a|^2 + |b|^2`` on a TPU v5e — the very terms the distance cancels.
+    """
+    g = partial.sum(axis=0)
+    diag = jnp.diagonal(g)
+    return jnp.maximum(diag[:, None] + diag[None, :] - 2.0 * g, 0.0)
+
+
+def _gram_kernel(a_ref, out_ref):
+    out_ref[0] = _gram_tile(a_ref[...].astype(jnp.float32))
 
 
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
@@ -62,41 +69,26 @@ def pairwise_sqdist_kernel(a, *, block_d: int = 512,
     m, d = a.shape
     assert d % block_d == 0, (d, block_d)
     nd = d // block_d
-    return pl.pallas_call(
-        functools.partial(_gram_kernel, nd=nd),
+    partial = pl.pallas_call(
+        _gram_kernel,
         grid=(nd,),
         in_specs=[pl.BlockSpec((m, block_d), lambda i: (0, i))],
-        out_specs=pl.BlockSpec((m, m), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((m, m), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((m, m), jnp.float32)],
+        out_specs=pl.BlockSpec((1, m, m), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((nd, m, m), jnp.float32),
         interpret=interpret,
     )(a)
+    return _sqdist_from_tile_grams(partial)
 
 
 def _fused_kernel(reset_ref, scale_ref, acc_ref, g_ref, newacc_ref,
-                  out_ref, gram_ref, *, nd: int):
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _init():
-        gram_ref[...] = jnp.zeros_like(gram_ref)
-
+                  out_ref):
     # select, NOT multiply-by-(1-reset): a Byzantine inf/NaN in the old
     # accumulator must be zeroed by the window reset (inf * 0 = NaN)
     a = acc_ref[...].astype(jnp.float32)
     a = jnp.where(reset_ref[0] != 0, jnp.zeros_like(a), a)
     new = a + g_ref[...].astype(jnp.float32) * scale_ref[0]
     newacc_ref[...] = new
-    gram_ref[...] += jax.lax.dot_general(
-        new, new, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
-
-    @pl.when(i == nd - 1)
-    def _finish():
-        g = gram_ref[...]
-        diag = jnp.diagonal(g)
-        sq = diag[:, None] + diag[None, :] - 2.0 * g
-        out_ref[...] = jnp.maximum(sq, 0.0)
+    out_ref[0] = _gram_tile(new)
 
 
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
@@ -114,8 +106,8 @@ def fused_accumulate_sqdist_kernel(acc, g, reset, scale, *,
     assert g.shape == (m, d), (acc.shape, g.shape)
     assert d % block_d == 0, (d, block_d)
     nd = d // block_d
-    return pl.pallas_call(
-        functools.partial(_fused_kernel, nd=nd),
+    new, partial = pl.pallas_call(
+        _fused_kernel,
         grid=(nd,),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),            # reset
@@ -125,13 +117,13 @@ def fused_accumulate_sqdist_kernel(acc, g, reset, scale, *,
         ],
         out_specs=[
             pl.BlockSpec((m, block_d), lambda i: (0, i)),     # new acc
-            pl.BlockSpec((m, m), lambda i: (0, 0)),           # sqdist
+            pl.BlockSpec((1, m, m), lambda i: (i, 0, 0)),     # tile Gram
         ],
         out_shape=[
             jax.ShapeDtypeStruct((m, d), jnp.float32),
-            jax.ShapeDtypeStruct((m, m), jnp.float32),
+            jax.ShapeDtypeStruct((nd, m, m), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((m, m), jnp.float32)],
         input_output_aliases={2: 0},
         interpret=interpret,
     )(reset, scale, acc, g)
+    return new, _sqdist_from_tile_grams(partial)

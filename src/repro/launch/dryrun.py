@@ -1,11 +1,14 @@
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 """Multi-pod dry run: lower + compile every (architecture x input shape)
 on the production meshes and extract the roofline raw terms.
 
-MUST be run as its own process (the two lines above execute before any
-other import so the host platform exposes 512 placeholder devices).
+MUST be run as its own process (the lines above execute before any other
+import so the CPU host platform exposes 512 placeholder devices — it is a
+lowering tool on fake devices, so it pins itself to the CPU even on a
+machine with a TPU).
 
 Usage:
     PYTHONPATH=src python -m repro.launch.dryrun --arch tinyllama-1.1b \
@@ -103,8 +106,6 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool = False,
         t_compile = time.time() - t0 - t_lower
 
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):      # jax <= 0.4.x: [dict]
-        cost = cost[0] if cost else {}
     mem = compiled.memory_analysis()
     hlo_txt = compiled.as_text()
     colls, coll_counts = collective_bytes(hlo_txt)

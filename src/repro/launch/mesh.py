@@ -13,15 +13,7 @@ jax import; tests/benches must keep seeing 1 device).
 from __future__ import annotations
 
 import jax
-
-
-def auto_axis_types(n_axes: int):
-    """``axis_types`` kwargs for Mesh/make_mesh, empty on jax versions
-    that predate ``jax.sharding.AxisType`` (everything was Auto there)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -38,7 +30,7 @@ def make_production_mesh(*, multi_pod: bool = False):
             "device_count=512 before importing jax")
     import numpy as np
     return jax.sharding.Mesh(np.asarray(devices).reshape(shape), axes,
-                             **auto_axis_types(len(axes)))
+                             axis_types=(AxisType.Auto,) * len(axes))
 
 
 def worker_axes(mesh) -> tuple:

@@ -44,14 +44,6 @@ from repro.optim import make_optimizer
 from repro.train import trainer as tr
 
 
-def _replicated(tree, mesh):
-    return jax.tree.map(
-        lambda s: jax.ShapeDtypeStruct(
-            s.shape, s.dtype, sharding=NamedSharding(mesh, P(*([None] *
-                                                               len(s.shape))))),
-        tree, is_leaf=lambda x: isinstance(x, jax.ShapeDtypeStruct))
-
-
 def make_sg_cfg(m: int, variant: str = "exact") -> Optional[sg.SafeguardConfig]:
     if variant == "mean":
         return None
@@ -101,31 +93,16 @@ def build_train(cfg: ModelConfig, shape: InputShape, mesh, *,
 
     # ---- abstract state with shardings --------------------------------
     params_a = T.init_abstract(cfg)
-    pspecs = sh.params_pspecs(params_a, mesh)
-    params_s = sh.with_shardings(params_a, pspecs, mesh)
-
-    opt_a = jax.eval_shape(opt.init, params_a)
-    opt_specs = jax.tree_util.tree_map_with_path(
-        lambda path, leaf: sh.param_pspec(path, leaf, mesh), opt_a)
-    opt_s = sh.with_shardings(opt_a, opt_specs, mesh)
-
-    if sg_cfg is not None:
-        sg_a = jax.eval_shape(
-            functools.partial(sg.init_state, sg_cfg), params_a)
-        gspecs = sh.stacked_grads_pspecs(pspecs, mesh)
-        sg_s = _sg_with_shardings(sg_a, sg_cfg, gspecs, mesh)
-    else:
-        sg_s = None
-
-    rng_a = jax.eval_shape(lambda: jax.random.PRNGKey(0))
-    state_s = tr.TrainState(
-        params=params_s, opt_state=opt_s, defense_state=sg_s,
-        attack_state=None,
-        step=jax.ShapeDtypeStruct((), jnp.int32,
-                                  sharding=NamedSharding(mesh, P())),
-        rng=jax.ShapeDtypeStruct(rng_a.shape, rng_a.dtype,
-                                 sharding=NamedSharding(mesh, P())),
-    )
+    sg_a = (jax.eval_shape(functools.partial(sg.init_state, sg_cfg), params_a)
+            if sg_cfg is not None else None)
+    state_a = tr.TrainState(
+        params=params_a, opt_state=jax.eval_shape(opt.init, params_a),
+        defense_state=sg_a, attack_state=None,
+        step=jax.ShapeDtypeStruct((), jnp.int32),
+        rng=jax.eval_shape(lambda: jax.random.PRNGKey(0)))
+    state_s = jax.tree.map(
+        lambda s, shd: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=shd),
+        state_a, train_state_shardings(state_a, mesh))
 
     batch_a = _abstract_batch(cfg, m, per, Lseq)
     batch_specs = sh.batch_pspecs(batch_a, mesh, m)
@@ -133,26 +110,47 @@ def build_train(cfg: ModelConfig, shape: InputShape, mesh, *,
     return step, (state_s, batch_s)
 
 
-def _sg_with_shardings(sg_a: sg.SafeguardState, sg_cfg, gspecs, mesh):
+def train_state_shardings(state: tr.TrainState, mesh) -> tr.TrainState:
+    """``NamedSharding`` of every leaf of a :class:`TrainState` (arrays or
+    ShapeDtypeStructs): parameters and optimizer state by the FSDP x TP
+    rules of ``launch.sharding``, the safeguard accumulators with their
+    worker rows on the data axes, everything else replicated."""
+    named = lambda spec: NamedSharding(mesh, spec)
+    rep = lambda x: named(P(*([None] * len(x.shape))))
+    pspecs = sh.params_pspecs(state.params, mesh)
+    ds = state.defense_state
+    if isinstance(ds, sg.SafeguardState):
+        ds = _sg_shardings(ds, sh.stacked_grads_pspecs(pspecs, mesh), mesh)
+    else:
+        ds = jax.tree.map(rep, ds)
+    return tr.TrainState(
+        params=jax.tree.map(named, pspecs,
+                            is_leaf=lambda x: isinstance(x, P)),
+        opt_state=jax.tree_util.tree_map_with_path(
+            lambda path, leaf: named(sh.param_pspec(path, leaf, mesh)),
+            state.opt_state),
+        defense_state=ds, attack_state=jax.tree.map(rep, state.attack_state),
+        step=rep(state.step), rng=rep(state.rng))
+
+
+def _sg_shardings(sg_a: sg.SafeguardState, gspecs, mesh) -> sg.SafeguardState:
+    named = lambda spec: NamedSharding(mesh, spec)
+
     def acc(tree):
         if tree is None:
             return None
-        if isinstance(tree, jax.ShapeDtypeStruct):
+        if hasattr(tree, "shape"):
             # flat accumulator (m_pad, d_pad): worker rows on the data
             # axes, feature columns on model (DESIGN.md §3/§6); sketch
             # matrix (m, rk): worker rows on the data axes.
             if sg_a.layout is not None:
-                spec = sh.flat_acc_pspec(mesh, sg_a.layout.d_padded)
-            else:
-                waxes = sh.mesh_lib.worker_axes(mesh)
-                spec = P(waxes if len(waxes) > 1 else waxes[0], None)
-            return jax.ShapeDtypeStruct(
-                tree.shape, tree.dtype, sharding=NamedSharding(mesh, spec))
-        return sh.with_shardings(tree, gspecs, mesh)
+                return named(sh.flat_acc_pspec(mesh, sg_a.layout.d_padded))
+            waxes = mesh_lib.worker_axes(mesh)
+            return named(P(waxes if len(waxes) > 1 else waxes[0], None))
+        return jax.tree.map(named, gspecs,
+                            is_leaf=lambda x: isinstance(x, P))
 
-    rep = lambda s: jax.ShapeDtypeStruct(
-        s.shape, s.dtype, sharding=NamedSharding(mesh, P(*([None] *
-                                                           len(s.shape)))))
+    rep = lambda s: named(P(*([None] * len(s.shape))))
     return sg.SafeguardState(
         good=rep(sg_a.good), step=rep(sg_a.step),
         A=acc(sg_a.A), B=acc(sg_a.B), evicted_at=rep(sg_a.evicted_at),

@@ -38,8 +38,8 @@ for t in range(12):
 good_ref = np.asarray(st.good)
 
 # ---- sharded (data=4 workers, model=2) ----------------------------------
-from repro.launch.mesh import auto_axis_types
-mesh = jax.make_mesh((4, 2), ("data", "model"), **auto_axis_types(2))
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 gspec = {"w": NamedSharding(mesh, P("data", None, "model")),
          "b": NamedSharding(mesh, P("data", "model"))}
 step = jax.jit(lambda s, g: safeguard_step(s, g, cfg))

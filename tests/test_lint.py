@@ -200,7 +200,7 @@ def test_sqrt_diff_walk_fires_on_fixture():
 
 def test_f64_walk_fires_on_fixture():
     m = _fx_tier2()
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         bad = jax.make_jaxpr(m.promotes_f64)(1.0)
     assert [v.rule for v in
             jaxpr_passes.find_f64(bad, "fx")] == ["f64"]
