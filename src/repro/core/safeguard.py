@@ -392,13 +392,18 @@ def _flat_update(acc, grads, gflat, reset, scale, cfg: SafeguardConfig,
     """One accumulator's flat-engine update -> (new_acc, sqdist).
 
     ``gflat`` is the flattened gradient matrix, materialized by the caller
-    only for the ``pallas_fused`` backend (``None`` otherwise)."""
+    only for the ``pallas_fused`` backend (``None`` otherwise).  The fused
+    kernel runs under the scope ``accumulate``; otherwise the accumulate
+    runs under ``accumulate`` and the distance pass under ``distance``."""
     if gflat is not None:
         from repro.kernels.safeguard_filter import fused_accumulate_sqdist
-        return fused_accumulate_sqdist(acc, gflat, reset, scale,
-                                       interpret=not _on_tpu())
-    new = _accumulate_flat(acc, grads, reset, scale, layout)
-    return new, _flat_sqdist(new, cfg)
+        with jax.named_scope("accumulate"):
+            return fused_accumulate_sqdist(acc, gflat, reset, scale,
+                                           interpret=not _on_tpu())
+    with jax.named_scope("accumulate"):
+        new = _accumulate_flat(acc, grads, reset, scale, layout)
+    with jax.named_scope("distance"):
+        return new, _flat_sqdist(new, cfg)
 
 
 # --------------------------------------------------------------------------
@@ -445,19 +450,25 @@ def safeguard_step(state: SafeguardState, grads, cfg: SafeguardConfig,
     reset_B = (t % cfg.T0) == 0
     reset_A = (t % cfg.T1) == 0
 
+    # named scopes for the device trace: accumulate, distance, filter,
+    # aggregate (the flat engine's are set in _flat_update)
     if cfg.use_sketch:
-        gsk = sk.sketch_tree(grads, k=cfg.sketch_k, reps=cfg.sketch_reps,
-                             seed=cfg.sketch_seed)
-        B = jnp.where(reset_B, 0.0, state.B) + gsk * inv_ngood
-        A = None
-        if cfg.mode == "double":
-            A = jnp.where(reset_A, 0.0, state.A) + gsk * inv_ngood
-        sqdist_B = sk.sketch_pairwise_sqdist(B)
-        sqdist_A = sk.sketch_pairwise_sqdist(A) if A is not None else None
+        with jax.named_scope("accumulate"):
+            gsk = sk.sketch_tree(grads, k=cfg.sketch_k, reps=cfg.sketch_reps,
+                                 seed=cfg.sketch_seed)
+            B = jnp.where(reset_B, 0.0, state.B) + gsk * inv_ngood
+            A = None
+            if cfg.mode == "double":
+                A = jnp.where(reset_A, 0.0, state.A) + gsk * inv_ngood
+        with jax.named_scope("distance"):
+            sqdist_B = sk.sketch_pairwise_sqdist(B)
+            sqdist_A = (sk.sketch_pairwise_sqdist(A) if A is not None
+                        else None)
     elif cfg.engine == "flat":
         layout = state.layout
-        gflat = (flatten_stacked(grads, layout)
-                 if cfg.backend == "pallas_fused" else None)
+        with jax.named_scope("accumulate"):
+            gflat = (flatten_stacked(grads, layout)
+                     if cfg.backend == "pallas_fused" else None)
         B, sqdist_B = _flat_update(state.B, grads, gflat, reset_B,
                                    inv_ngood, cfg, layout)
         A, sqdist_A = None, None
@@ -465,55 +476,65 @@ def safeguard_step(state: SafeguardState, grads, cfg: SafeguardConfig,
             A, sqdist_A = _flat_update(state.A, grads, gflat, reset_A,
                                        inv_ngood, cfg, layout)
         if acc_sharding is not None:
-            B = jax.lax.with_sharding_constraint(B, acc_sharding)
-            if A is not None:
-                A = jax.lax.with_sharding_constraint(A, acc_sharding)
+            with jax.named_scope("accumulate"):
+                B = jax.lax.with_sharding_constraint(B, acc_sharding)
+                if A is not None:
+                    A = jax.lax.with_sharding_constraint(A, acc_sharding)
     else:
-        B = _accumulate_exact(state.B, grads, reset_B, inv_ngood,
-                              cfg.acc_dtype)
-        A = None
-        if cfg.mode == "double":
-            A = _accumulate_exact(state.A, grads, reset_A, inv_ngood,
+        with jax.named_scope("accumulate"):
+            B = _accumulate_exact(state.B, grads, reset_B, inv_ngood,
                                   cfg.acc_dtype)
-        sqdist_B = tu.tree_pairwise_sqdist(B)
-        sqdist_A = tu.tree_pairwise_sqdist(A) if A is not None else None
+            A = None
+            if cfg.mode == "double":
+                A = _accumulate_exact(state.A, grads, reset_A, inv_ngood,
+                                      cfg.acc_dtype)
+        with jax.named_scope("distance"):
+            sqdist_B = tu.tree_pairwise_sqdist(B)
+            sqdist_A = (tu.tree_pairwise_sqdist(A) if A is not None
+                        else None)
 
-    if cfg.rule == "empirical":
-        okB, medB, thB, scoresB = _empirical_filter(
-            sqdist_B, good, m, cfg.threshold_scale, cfg.threshold_floor)
-        if cfg.mode == "double":
-            okA, medA, thA, _ = _empirical_filter(
-                sqdist_A, good, m, cfg.threshold_scale, cfg.threshold_floor)
+    with jax.named_scope("filter"):
+        if cfg.rule == "empirical":
+            okB, medB, thB, scoresB = _empirical_filter(
+                sqdist_B, good, m, cfg.threshold_scale, cfg.threshold_floor)
+            if cfg.mode == "double":
+                okA, medA, thA, _ = _empirical_filter(
+                    sqdist_A, good, m, cfg.threshold_scale,
+                    cfg.threshold_floor)
+            else:
+                okA, medA, thA = jnp.ones_like(okB), medB, thB
         else:
-            okA, medA, thA = jnp.ones_like(okB), medB, thB
-    else:
-        okB, medB, thB, scoresB = _theoretical_filter(
-            sqdist_B, good, m, cfg.thresh0)
-        if cfg.mode == "double":
-            okA, medA, thA, _ = _theoretical_filter(
-                sqdist_A, good, m, cfg.thresh1)
-        else:
-            okA, medA, thA = jnp.ones_like(okB), medB, thB
+            okB, medB, thB, scoresB = _theoretical_filter(
+                sqdist_B, good, m, cfg.thresh0)
+            if cfg.mode == "double":
+                okA, medA, thA, _ = _theoretical_filter(
+                    sqdist_A, good, m, cfg.thresh1)
+            else:
+                okA, medA, thA = jnp.ones_like(okB), medB, thB
 
-    new_good = good & okA & okB
+        new_good = good & okA & okB
 
-    newly_evicted = good & ~new_good
-    evicted_at = jnp.where(newly_evicted, t, evicted_at)
+        newly_evicted = good & ~new_good
+        evicted_at = jnp.where(newly_evicted, t, evicted_at)
 
-    # SGD direction over good_t (pre-filter, paper line 12) or good_{t+1}.
-    agg_mask = good if cfg.aggregate_prefilter else new_good
-    agg = tu.tree_masked_mean(grads, agg_mask)
+    with jax.named_scope("aggregate"):
+        # SGD direction over good_t (pre-filter, paper line 12) or
+        # good_{t+1}.
+        agg_mask = good if cfg.aggregate_prefilter else new_good
+        agg = tu.tree_masked_mean(grads, agg_mask)
 
-    if cfg.nu > 0.0:
-        if rng is None:
-            raise ValueError("nu > 0 requires an rng key")
-        keys = jax.random.split(rng, len(jax.tree_util.tree_leaves(agg)))
-        keys = iter(list(keys))
+        if cfg.nu > 0.0:
+            if rng is None:
+                raise ValueError("nu > 0 requires an rng key")
+            keys = jax.random.split(rng,
+                                    len(jax.tree_util.tree_leaves(agg)))
+            keys = iter(list(keys))
 
-        def add_noise(leaf):
-            k = next(keys)
-            return leaf + cfg.nu * jax.random.normal(k, leaf.shape, leaf.dtype)
-        agg = jax.tree.map(add_noise, agg)
+            def add_noise(leaf):
+                k = next(keys)
+                return leaf + cfg.nu * jax.random.normal(k, leaf.shape,
+                                                         leaf.dtype)
+            agg = jax.tree.map(add_noise, agg)
 
     new_state = SafeguardState(
         good=new_good,
@@ -523,9 +544,10 @@ def safeguard_step(state: SafeguardState, grads, cfg: SafeguardConfig,
         evicted_at=evicted_at,
         layout=state.layout,
     )
-    dist_B = jnp.sqrt(jnp.maximum(sqdist_B, 0.0))[:, medB]
-    dist_A = (jnp.sqrt(jnp.maximum(sqdist_A, 0.0))[:, medA]
-              if sqdist_A is not None else dist_B)
+    with jax.named_scope("filter"):
+        dist_B = jnp.sqrt(jnp.maximum(sqdist_B, 0.0))[:, medB]
+        dist_A = (jnp.sqrt(jnp.maximum(sqdist_A, 0.0))[:, medA]
+                  if sqdist_A is not None else dist_B)
     info = {
         "n_good": n_good,
         "med_B": medB,

@@ -112,4 +112,5 @@ def flash_attention_kernel(q, k, v, *, block_q: int = 128,
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention_kernel",
     )(q, k, v)

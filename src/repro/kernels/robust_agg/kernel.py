@@ -53,5 +53,6 @@ def sorted_reduce_kernel(g, *, trim: int = 0, median: bool = False,
         out_specs=pl.BlockSpec((1, block_d), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, d), jnp.float32),
         interpret=interpret,
+        name="sorted_reduce_kernel",
     )(g)
     return out[0]

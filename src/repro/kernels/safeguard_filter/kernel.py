@@ -76,6 +76,7 @@ def pairwise_sqdist_kernel(a, *, block_d: int = 512,
         out_specs=pl.BlockSpec((1, m, m), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((nd, m, m), jnp.float32),
         interpret=interpret,
+        name="pairwise_sqdist_kernel",
     )(a)
     return _sqdist_from_tile_grams(partial)
 
@@ -125,5 +126,6 @@ def fused_accumulate_sqdist_kernel(acc, g, reset, scale, *,
         ],
         input_output_aliases={2: 0},
         interpret=interpret,
+        name="fused_accumulate_sqdist_kernel",
     )(reset, scale, acc, g)
     return new, _sqdist_from_tile_grams(partial)

@@ -914,7 +914,8 @@ def mamba2_block_apply(params, cfg, x, *, cache=None):
             zp = lambda t: jnp.pad(t, [(0, 0), (0, pad)]
                                    + [(0, 0)] * (t.ndim - 2))
             xs, dt, Bm, Cm = map(zp, (xs, dt, Bm, Cm))
-        y, final = ssd_scan(xs, dt, A, Bm, Cm, cfg.ssm_chunk)
+        with jax.named_scope("ssd"):
+            y, final = ssd_scan(xs, dt, A, Bm, Cm, cfg.ssm_chunk)
         y = y[:, :L]
         new_ssm = final.astype(x.dtype)
     else:

@@ -175,37 +175,46 @@ def _apply_layer(p, cfg, kind, x, positions, cache, cache_pos,
     ``cache`` is the decode-time state (None during train/prefill);
     ``max_seq > 0`` marks prefill: attention layers then emit ring-packed
     caches of that size (recurrent layers always emit their final state).
+    The sequence mixer runs under the named scope ``mixer``, the MLP or
+    MoE under ``mlp``.
     """
     # anchor the residual stream: replicated over the model axis
     x = L.constrain(x, L._U, L._U, None)
     aux = jnp.zeros((), f32)
     if kind == "ssm":
         h = L.apply_norm(p["ln"], x, cfg.norm)
-        out, new_cache = L.mamba2_block_apply(p["mixer"], cfg, h, cache=cache)
+        with jax.named_scope("mixer"):
+            out, new_cache = L.mamba2_block_apply(p["mixer"], cfg, h,
+                                                  cache=cache)
         return x + out, new_cache, aux
     if kind == "rec":
         h = L.apply_norm(p["ln1"], x, cfg.norm)
-        out, new_cache = L.rglru_block_apply(p["rec"], cfg, h, cache=cache)
+        with jax.named_scope("mixer"):
+            out, new_cache = L.rglru_block_apply(p["rec"], cfg, h,
+                                                 cache=cache)
         x = x + out
         h = L.apply_norm(p["ln2"], x, cfg.norm)
-        x = x + L.mlp_apply(p["mlp"], cfg.mlp, h)
+        with jax.named_scope("mlp"):
+            x = x + L.mlp_apply(p["mlp"], cfg.mlp, h)
         return x, new_cache, aux
 
     h = L.apply_norm(p["ln1"], x, cfg.norm)
-    if kind.startswith("mla"):
-        out, new_cache = L.mla_block_apply(
-            p["attn"], cfg, h, positions=positions, cache=cache,
-            cache_pos=cache_pos, max_seq=max_seq)
-    else:
-        out, new_cache = L.attn_block_apply(
-            p["attn"], cfg, h, positions=positions, cache=cache,
-            cache_pos=cache_pos, max_seq=max_seq)
+    with jax.named_scope("mixer"):
+        if kind.startswith("mla"):
+            out, new_cache = L.mla_block_apply(
+                p["attn"], cfg, h, positions=positions, cache=cache,
+                cache_pos=cache_pos, max_seq=max_seq)
+        else:
+            out, new_cache = L.attn_block_apply(
+                p["attn"], cfg, h, positions=positions, cache=cache,
+                cache_pos=cache_pos, max_seq=max_seq)
     x = x + out
     h = L.apply_norm(p["ln2"], x, cfg.norm)
-    if "moe" in p:
-        out, aux = L.moe_apply(p["moe"], cfg, h)
-    else:
-        out = L.mlp_apply(p["mlp"], cfg.mlp, h)
+    with jax.named_scope("mlp"):
+        if "moe" in p:
+            out, aux = L.moe_apply(p["moe"], cfg, h)
+        else:
+            out = L.mlp_apply(p["mlp"], cfg.mlp, h)
     return x + out, new_cache, aux
 
 
@@ -245,20 +254,23 @@ def forward(params, cfg: ModelConfig, inputs, *, positions=None,
         max_seq = 0
     want_cache = mode in ("prefill", "decode")
 
-    if cfg.embed_stub:
-        x = inputs.astype(cfg.dtype)
-        B, Lq = x.shape[0], x.shape[1]
-    else:
-        B, Lq = inputs.shape
-        x = params["embed"][inputs].astype(cfg.dtype)
+    with jax.named_scope("embed"):
+        if cfg.embed_stub:
+            x = inputs.astype(cfg.dtype)
+            B, Lq = x.shape[0], x.shape[1]
+        else:
+            B, Lq = inputs.shape
+            x = params["embed"][inputs].astype(cfg.dtype)
 
-    cache_pos = cache["pos"] if cache is not None else jnp.zeros((), jnp.int32)
-    if positions is None:
-        positions = _default_positions(cfg, B, Lq, cache_pos)
-    if cfg.pos == "sinusoidal":
-        pos_emb = L.sinusoidal_embedding(
-            positions if positions.ndim == 2 else positions[0], cfg.d_model)
-        x = x + pos_emb.astype(cfg.dtype)
+        cache_pos = (cache["pos"] if cache is not None
+                     else jnp.zeros((), jnp.int32))
+        if positions is None:
+            positions = _default_positions(cfg, B, Lq, cache_pos)
+        if cfg.pos == "sinusoidal":
+            pos_emb = L.sinusoidal_embedding(
+                positions if positions.ndim == 2 else positions[0],
+                cfg.d_model)
+            x = x + pos_emb.astype(cfg.dtype)
 
     aux_total = jnp.zeros((), f32)
     new_cache: Optional[Dict[str, Any]] = {} if want_cache else None
@@ -331,12 +343,13 @@ def forward(params, cfg: ModelConfig, inputs, *, positions=None,
             new_cache["pre_blocks"] = new_pre
             new_cache["blocks"] = new_blocks
 
-    x = L.apply_norm(params["final_norm"], x, cfg.norm)
-    head = (params["embed"].T if cfg.tie_embeddings
-            else params["lm_head"])
-    logits = jnp.einsum("bld,dv->blv", x, head.astype(x.dtype),
-                        preferred_element_type=f32)
-    logits = L.constrain(logits, L._U, L._U, L._mdl(cfg.vocab_size))
+    with jax.named_scope("head"):
+        x = L.apply_norm(params["final_norm"], x, cfg.norm)
+        head = (params["embed"].T if cfg.tie_embeddings
+                else params["lm_head"])
+        logits = jnp.einsum("bld,dv->blv", x, head.astype(x.dtype),
+                            preferred_element_type=f32)
+        logits = L.constrain(logits, L._U, L._U, L._mdl(cfg.vocab_size))
 
     if want_cache:
         new_cache["pos"] = cache_pos + Lq
@@ -378,7 +391,8 @@ def loss_fn(params, cfg: ModelConfig, batch, *, positions=None):
         inputs, labels = batch["tokens"], batch["tokens"]
     logits, _, aux = forward(params, cfg, inputs, positions=positions,
                              mode="train")
-    loss = cross_entropy(logits[:, :-1], labels[:, 1:])
+    with jax.named_scope("head"):
+        loss = cross_entropy(logits[:, :-1], labels[:, 1:])
     if cfg.n_experts > 0:
         loss = loss + cfg.router_aux_coef * aux["moe_aux"] / cfg.n_layers
     return loss

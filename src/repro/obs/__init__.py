@@ -21,9 +21,10 @@ Layers:
   ``python -m repro.obs.live`` tail/alerts CLI (DESIGN.md §17);
 * :mod:`repro.obs.alerts`  — pure rule engine over heartbeat streams
   (NaN guard, eviction storms, threshold runaway, stalled saddle
-  escape, step-rate collapse);
-* :mod:`repro.obs.perfetto` — Chrome-trace/Perfetto exporter for
-  PhaseTimer spans + AOT profiles + collective counters.
+  escape, step-rate collapse).
+
+Device time per phase comes from the JAX profiler's trace of the chip,
+read by the step's named scopes (``train.trainer.make_train_step``).
 """
 
 from repro.obs.schema import (MetricSpec, SchemaError, INFO, METRICS,

@@ -37,20 +37,14 @@ class PhaseTimer:
 
     def __init__(self):
         self.seconds: Dict[str, float] = {}
-        # full (name, enter, exit, depth) spans in perf_counter seconds —
-        # unlike ``seconds`` these are NOT innermost-only: a span covers
-        # its children, which is exactly the nesting a Chrome-trace /
-        # Perfetto flame view expects (repro.obs.perfetto)
-        self.spans: list = []
         self._stack: list = []          # [(name, started_at), ...]
 
     @contextlib.contextmanager
     def phase(self, name: str):
-        enter = now = time.perf_counter()
+        now = time.perf_counter()
         if self._stack:                 # pause the enclosing phase
             outer, t0 = self._stack[-1]
             self.seconds[outer] = self.seconds.get(outer, 0.0) + now - t0
-        depth = len(self._stack)
         self._stack.append((name, now))
         try:
             yield self
@@ -58,7 +52,6 @@ class PhaseTimer:
             now = time.perf_counter()
             _, t0 = self._stack.pop()
             self.seconds[name] = self.seconds.get(name, 0.0) + now - t0
-            self.spans.append((name, enter, now, depth))
             if self._stack:             # resume the enclosing phase
                 outer, _ = self._stack[-1]
                 self._stack[-1] = (outer, now)

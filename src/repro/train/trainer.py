@@ -190,100 +190,116 @@ def make_train_step(loss_fn: Callable, opt: OptimizerBundle, *,
         else:
             rng, k_attack, k_noise = jax.random.split(state.rng, 3)
 
-        # (1) per-worker gradients
-        vg = jax.value_and_grad(loss_fn)
-        losses, grads = jax.vmap(lambda wb: vg(state.params, wb),
-                                 spmd_axis_name=spmd_axis_name)(batch)
+        # (1) per-worker gradients.  Each phase runs under a named scope
+        # (fwd_bwd, attack, defense, optimizer, telemetry) that reaches the
+        # compiled step's op_name metadata: the device trace's per-phase
+        # times are read by these names
+        with jax.named_scope("fwd_bwd"):
+            vg = jax.value_and_grad(loss_fn)
+            losses, grads = jax.vmap(lambda wb: vg(state.params, wb),
+                                     spmd_axis_name=spmd_axis_name)(batch)
 
         # (2) Byzantine simulation — the attack state already absorbed the
         # previous step's public defense feedback (observe, below)
-        grads, attack_state = attack.act(grads, byz_mask, state.attack_state,
-                                         state.step, k_attack)
+        with jax.named_scope("attack"):
+            grads, attack_state = attack.act(grads, byz_mask,
+                                             state.attack_state, state.step,
+                                             k_attack)
 
         # (3) aggregation through the Defense protocol (DESIGN.md §12)
-        metrics: Dict[str, jax.Array] = {
-            "loss": losses.mean(),
-            "honest_loss": (losses * (~byz_mask)).sum()
-            / jnp.maximum((~byz_mask).sum(), 1),
-        }
+        with jax.named_scope("telemetry"):
+            metrics: Dict[str, jax.Array] = {
+                "loss": losses.mean(),
+                "honest_loss": (losses * (~byz_mask)).sum()
+                / jnp.maximum((~byz_mask).sum(), 1),
+            }
         ctx = {"rng": k_noise, "acc_sharding": acc_sharding}
-        if defense.needs_held_batch:
-            if held_batch is None:
-                raise ValueError(f"{defense.name} needs a held-out batch")
-            ctx["scores"] = zeno_scores(loss_fn, state.params, grads,
-                                        held_batch, eta=zeno_eta,
-                                        rho=zeno_rho)
-        agg, defense_state, info = defense.aggregate(state.defense_state,
-                                                     grads, ctx)
+        with jax.named_scope("defense"):
+            if defense.needs_held_batch:
+                if held_batch is None:
+                    raise ValueError(f"{defense.name} needs a held-out batch")
+                ctx["scores"] = zeno_scores(loss_fn, state.params, grads,
+                                            held_batch, eta=zeno_eta,
+                                            rho=zeno_rho)
+            agg, defense_state, info = defense.aggregate(state.defense_state,
+                                                         grads, ctx)
         # flight-recorder schema check (DESIGN.md §15): tracer shapes and
         # dtypes are static, so this runs once per program trace and is
         # free per step — a defense renaming a key or changing a shape
         # class fails loudly here instead of corrupting campaign traces
         obs_schema.validate_info(info, m, where=f"defense:{defense.name}")
-        # dissimilarity-aware trace layer (DESIGN.md §13): the measured
-        # zeta^2 heterogeneity of the reported gradients — over the
-        # simulation's ground-truth honest set and over the defense's
-        # live good set (what a real master could compute).  Two O(m d)
-        # passes; ``trace_zeta=False`` drops them from the hot path
-        # (the at-scale lowering of launch/specs does)
-        if trace_zeta:
-            metrics["zeta_sq"] = het_lib.zeta_sq(grads, ~byz_mask)
-            metrics["zeta_good_sq"] = het_lib.zeta_sq(grads, info["good"])
-        if defense.stateful:
-            metrics["n_good"] = info["n_good"]
-            metrics["caught_byz"] = (byz_mask & ~info["good"]).sum()
-            metrics["evicted_honest"] = (~byz_mask & ~info["good"]).sum()
-            metrics["good"] = info["good"]
-            if "restored" in info:
-                metrics["restored"] = info["restored"].sum()
-        # per-worker detection statistics + live thresholds, traced when
-        # the defense publishes them — the obs event layer reconstructs
-        # evictions/threshold-crossings from exactly these surfaces
-        # (Fig-2a reads them from the engine's traces instead of
-        # re-implementing the training loop)
-        for k in ("dist_to_med_B", "dist_to_med_A",
-                  "threshold_B", "threshold_A"):
-            if k in info:
-                metrics[k] = jnp.asarray(info[k], jnp.float32)
-        # adaptive-attack controller level consumed by this step's act()
-        # (observe has not folded this step's feedback yet) — its
-        # reversals are the attack's phase boundaries
-        if attack.observe is not None:
-            lvl = atk_lib.controller_level(state.attack_state)
-            if lvl is not None:
-                metrics["attack_level"] = lvl
-        # second-order trace lane (DESIGN.md §14): analytic saddle
-        # diagnostics of the current iterate, traced like zeta_sq
-        if so_probe is not None:
-            metrics.update(so_probe(state.params))
+        with jax.named_scope("telemetry"):
+            # dissimilarity-aware trace layer (DESIGN.md §13): the measured
+            # zeta^2 heterogeneity of the reported gradients — over the
+            # simulation's ground-truth honest set and over the defense's
+            # live good set (what a real master could compute).  Two O(m d)
+            # passes; ``trace_zeta=False`` drops them from the hot path
+            # (the at-scale lowering of launch/specs does)
+            if trace_zeta:
+                metrics["zeta_sq"] = het_lib.zeta_sq(grads, ~byz_mask)
+                metrics["zeta_good_sq"] = het_lib.zeta_sq(grads,
+                                                          info["good"])
+            if defense.stateful:
+                metrics["n_good"] = info["n_good"]
+                metrics["caught_byz"] = (byz_mask & ~info["good"]).sum()
+                metrics["evicted_honest"] = (~byz_mask & ~info["good"]).sum()
+                metrics["good"] = info["good"]
+                if "restored" in info:
+                    metrics["restored"] = info["restored"].sum()
+            # per-worker detection statistics + live thresholds, traced
+            # when the defense publishes them — the obs event layer
+            # reconstructs evictions/threshold-crossings from exactly these
+            # surfaces (Fig-2a reads them from the engine's traces instead
+            # of re-implementing the training loop)
+            for k in ("dist_to_med_B", "dist_to_med_A",
+                      "threshold_B", "threshold_A"):
+                if k in info:
+                    metrics[k] = jnp.asarray(info[k], jnp.float32)
+            # adaptive-attack controller level consumed by this step's
+            # act() (observe has not folded this step's feedback yet) — its
+            # reversals are the attack's phase boundaries
+            if attack.observe is not None:
+                lvl = atk_lib.controller_level(state.attack_state)
+                if lvl is not None:
+                    metrics["attack_level"] = lvl
+            # second-order trace lane (DESIGN.md §14): analytic saddle
+            # diagnostics of the current iterate, traced like zeta_sq
+            if so_probe is not None:
+                metrics.update(so_probe(state.params))
         # the paper's saddle-escape perturbation: isotropic noise on the
         # aggregated direction when its norm says "near-stationary"
         if perturb == "sgd_escape":
-            agg_norm = jnp.sqrt(tu.tree_sq_norm(agg))
-            on = (agg_norm <= jnp.asarray(escape_thresh, f32)).astype(f32)
-            leaves = jax.tree_util.tree_leaves(agg)
-            keys = iter(list(jax.random.split(k_escape, len(leaves))))
+            with jax.named_scope("optimizer"):
+                agg_norm = jnp.sqrt(tu.tree_sq_norm(agg))
+                on = (agg_norm <= jnp.asarray(escape_thresh, f32)
+                      ).astype(f32)
+                leaves = jax.tree_util.tree_leaves(agg)
+                keys = iter(list(jax.random.split(k_escape, len(leaves))))
 
-            def _noise(leaf):
-                k = next(keys)
-                xi = jax.random.normal(k, leaf.shape, f32)
-                return (leaf.astype(f32)
-                        + on * jnp.asarray(escape_nu, f32) * xi
-                        ).astype(leaf.dtype)
-            agg = jax.tree.map(_noise, agg)
+                def _noise(leaf):
+                    k = next(keys)
+                    xi = jax.random.normal(k, leaf.shape, f32)
+                    return (leaf.astype(f32)
+                            + on * jnp.asarray(escape_nu, f32) * xi
+                            ).astype(leaf.dtype)
+                agg = jax.tree.map(_noise, agg)
             metrics["escape_on"] = on
-        feedback = atk_lib.defense_feedback(info, m)
 
         # feedback coupling (DESIGN.md §11): adaptive attacks fold this
         # step's public defense outputs into the state the next step's
         # act() will read — the carry keeps the loop scan/vmap-able
-        if attack.observe is not None:
-            attack_state = attack.observe(attack_state, feedback, byz_mask)
+        with jax.named_scope("attack"):
+            feedback = atk_lib.defense_feedback(info, m)
+            if attack.observe is not None:
+                attack_state = attack.observe(attack_state, feedback,
+                                              byz_mask)
 
         # (4) optimizer
-        params, opt_state = opt.update(agg, state.opt_state, state.params,
-                                       state.step)
-        metrics["grad_norm"] = jnp.sqrt(tu.tree_sq_norm(agg))
+        with jax.named_scope("optimizer"):
+            params, opt_state = opt.update(agg, state.opt_state,
+                                           state.params, state.step)
+        with jax.named_scope("telemetry"):
+            metrics["grad_norm"] = jnp.sqrt(tu.tree_sq_norm(agg))
         obs_schema.validate_metrics(metrics, m,
                                     where=f"train_step:{defense.name}")
         new_state = TrainState(params=params, opt_state=opt_state,
@@ -436,6 +452,9 @@ class Trainer:
         # and trace_arrays() stacks them, matching scan_trial's layout
         self.traces: Dict[str, list] = {}
         self._routed_keys: set = set()
+        # steps dispatched by run(): names each step's profiler span
+        # without reading ``state.step`` back from the device
+        self.dispatched = 0
 
     def trace_arrays(self) -> Dict[str, "np.ndarray"]:
         """Stack the accumulated per-step vector metrics into
@@ -445,6 +464,13 @@ class Trainer:
                 for k, vs in self.traces.items()}
 
     def run(self, steps: int, verbose: bool = True):
+        """Dispatch ``steps`` steps.  Each runs inside the profiler span
+        ``repro.step`` (``step_num`` = :attr:`dispatched`, a host counter,
+        so no step waits on the device to be named), with the spans
+        ``repro.batch`` (the iterators), ``repro.dispatch`` (the step
+        call) and ``repro.log`` (the log-boundary record, which reads the
+        device) inside it.  Off the profiler each span costs about a
+        microsecond of host time."""
         collector = self.collector
         if collector is None and verbose:
             from repro.obs import live as live_lib
@@ -452,37 +478,46 @@ class Trainer:
                 name=self.name, echo=print)
         t0 = time.time()
         for i in range(steps):
-            batch = next(self.data_iter)
-            if self.held_iter is not None:
-                held = next(self.held_iter)
-                self.state, metrics = self.step_fn(self.state, batch, held)
-            else:
-                self.state, metrics = self.step_fn(self.state, batch)
-            # route non-scalar metrics to the trace path (history holds
-            # scalars only); surface what was routed once per run so the
-            # keys are not silently invisible
-            vec = {k: v for k, v in metrics.items()
-                   if getattr(v, "ndim", 0) != 0}
-            for k, v in vec.items():
-                self.traces.setdefault(k, []).append(v)
-            new_keys = set(vec) - self._routed_keys
-            if new_keys:
-                self._routed_keys |= new_keys
-                if verbose:
-                    print(f"[{self.name}] non-scalar metrics routed to "
-                          f".traces (not history): {sorted(new_keys)}")
-            if (i + 1) % self.log_every == 0 or i == steps - 1:
-                rec = {k: float(v) for k, v in metrics.items()
-                       if getattr(v, "ndim", 0) == 0}
-                rec["step"] = int(self.state.step)
-                if self.eval_fn is not None:
-                    rec.update(self.eval_fn(self.state.params))
-                rec["wall_s"] = time.time() - t0
-                self.history.append(rec)
-                # one telemetry path for interactive runs and campaign
-                # cells: the record's tap-surface subset is a heartbeat
-                # (the collector stamps step_rate/t_wall and echoes it)
-                if collector is not None:
-                    collector.tap({k: v for k, v in rec.items()
-                                   if k in obs_schema.TAP})
+            with jax.profiler.StepTraceAnnotation("repro.step",
+                                                  step_num=self.dispatched):
+                with jax.profiler.TraceAnnotation("repro.batch"):
+                    batch = next(self.data_iter)
+                    held = (next(self.held_iter)
+                            if self.held_iter is not None else None)
+                with jax.profiler.TraceAnnotation("repro.dispatch"):
+                    if held is not None:
+                        self.state, metrics = self.step_fn(self.state, batch,
+                                                           held)
+                    else:
+                        self.state, metrics = self.step_fn(self.state, batch)
+                self.dispatched += 1
+                # route non-scalar metrics to the trace path (history holds
+                # scalars only); surface what was routed once per run so
+                # the keys are not silently invisible
+                vec = {k: v for k, v in metrics.items()
+                       if getattr(v, "ndim", 0) != 0}
+                for k, v in vec.items():
+                    self.traces.setdefault(k, []).append(v)
+                new_keys = set(vec) - self._routed_keys
+                if new_keys:
+                    self._routed_keys |= new_keys
+                    if verbose:
+                        print(f"[{self.name}] non-scalar metrics routed to "
+                              f".traces (not history): {sorted(new_keys)}")
+                if (i + 1) % self.log_every == 0 or i == steps - 1:
+                    with jax.profiler.TraceAnnotation("repro.log"):
+                        rec = {k: float(v) for k, v in metrics.items()
+                               if getattr(v, "ndim", 0) == 0}
+                        rec["step"] = int(self.state.step)
+                        if self.eval_fn is not None:
+                            rec.update(self.eval_fn(self.state.params))
+                        rec["wall_s"] = time.time() - t0
+                        self.history.append(rec)
+                        # one telemetry path for interactive runs and
+                        # campaign cells: the record's tap-surface subset is
+                        # a heartbeat (the collector stamps step_rate/t_wall
+                        # and echoes it)
+                        if collector is not None:
+                            collector.tap({k: v for k, v in rec.items()
+                                           if k in obs_schema.TAP})
         return self.history

@@ -12,8 +12,7 @@ documented in DESIGN.md §17).
 Also covered: the LiveCollector host side (ring, heartbeat files,
 step_rate, lane mapping, never-raise), the alert-rule catalog on
 synthetic streams (each rule fires exactly on its trigger and never on
-a clean stream), the Chrome-trace schema contract, and the regression
-gate's offline comparison path."""
+a clean stream), and the regression gate's offline comparison path."""
 
 import json
 from pathlib import Path
@@ -27,9 +26,7 @@ from repro.campaign import engine
 from repro.campaign.run import CAMPAIGNS
 from repro.obs import alerts as alerts_lib
 from repro.obs import live as live_lib
-from repro.obs import perfetto
 from repro.obs import schema as obs_schema
-from repro.obs.profile import PhaseTimer
 
 STEPS = 40
 TAP_EVERY = 10
@@ -402,61 +399,6 @@ def test_rules_disarm_without_their_keys():
     """A program that taps only loss arms nothing but nan_guard."""
     beats = [{"step": 10 * i, "loss": 1.0} for i in range(8)]
     assert alerts_lib.extract_alerts(beats, cell="c") == []
-
-
-# ------------------------------------------------ perfetto schema
-
-
-def test_chrome_trace_schema_roundtrip():
-    pt = PhaseTimer()
-    with pt.phase("outer"):
-        with pt.phase("inner"):
-            pass
-    rec = {"lower_s": 0.1, "compile_s": 0.2, "execute_s": 0.05,
-           "hlo": {"collective_bytes": {"all-reduce": 128.0},
-                   "collective_counts": {"all-reduce": 2}}}
-    events = [perfetto.meta_event("process_name", "prog", pid=1)]
-    events += perfetto.profile_events(rec, pid=1, label="prog")
-    events += perfetto.timer_events(pt, pid=0)
-    trace = perfetto.chrome_trace(events)
-    out = perfetto.validate_chrome_trace(json.loads(json.dumps(trace)))
-    phases = {e["ph"] for e in out}
-    assert {"X", "C", "M"} <= phases
-    spans = [e for e in out if e["ph"] == "X"]
-    assert {"lower", "compile", "execute", "outer", "inner"} <= {
-        e["name"] for e in spans}
-    assert all(e["dur"] >= 0 for e in spans)
-    # the nested PhaseTimer span is contained in its parent
-    named = {e["name"]: e for e in spans}
-    assert named["inner"]["ts"] >= named["outer"]["ts"]
-    counters = [e for e in out if e["ph"] == "C"]
-    assert counters and all(isinstance(e["args"], dict)
-                            for e in counters)
-
-
-@pytest.mark.parametrize("bad,msg", [
-    ({"traceEvents": "nope"}, "must be a list"),
-    ({"traceEvents": [{"ph": "X", "pid": 0}]}, "missing 'name'"),
-    ({"traceEvents": [{"name": "a", "ph": "Z", "pid": 0, "ts": 0}]},
-     "unknown phase"),
-    ({"traceEvents": [{"name": "a", "ph": "X", "pid": 0, "ts": 0}]},
-     "dur"),
-    ({"traceEvents": [{"name": "a", "ph": "C", "pid": 0, "ts": 0}]},
-     "args"),
-    ({"traceEvents": [{"name": "a", "ph": "X", "pid": 0, "dur": 1}]},
-     "'ts' must be a number"),
-])
-def test_chrome_trace_schema_rejects_malformed(bad, msg):
-    with pytest.raises(ValueError, match=msg):
-        perfetto.validate_chrome_trace(bad)
-
-
-def test_zero_collectives_emit_no_counter_track():
-    rec = {"lower_s": 0.1, "compile_s": 0.2, "execute_s": 0.05,
-           "hlo": {"collective_bytes": {"all-reduce": 0.0},
-                   "collective_counts": {"all-reduce": 0}}}
-    events = perfetto.profile_events(rec)
-    assert not [e for e in events if e["ph"] == "C"]
 
 
 # ------------------------------------------------ regression gate

@@ -8,6 +8,8 @@ described inside a fixture, never at import, so every pytest-xdist worker
 collects the same tests and only the worker given this file loads the TPU
 compiler.  All such compiles live in this one file."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -90,3 +92,38 @@ def test_smoke_step_compiles_for_v5e_and_fits(one_chip, monkeypatch):
     live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert live < HBM_BYTES
+
+
+def test_scoped_smoke_step_names_its_gram_kernel_for_v5e(one_chip,
+                                                          monkeypatch):
+    """The v5e step of a smoke safeguard run keeps the Gram kernel's
+    instruction name, ``pairwise_sqdist_kernel.N``, by which a device
+    trace finds the kernel, and its ``op_name`` lies under the defense's
+    distance pass."""
+    from repro import configs as C
+    from repro.launch import train as train_lib
+    m, seq = 4, 256
+    args = train_lib.parse_args([
+        "--workers", str(m), "--byz", "1", "--batch", str(m), "--seq",
+        str(seq), "--attack", "sign_flip", "--defense", "safeguard_double",
+        "--t0", "2", "--t1", "4", "--floor", "0.01", "--lr", "0.005"])
+    monkeypatch.setattr(sg, "_on_tpu", lambda: True)
+    built = {}
+
+    def build():
+        built["trainer"] = train_lib.build_trainer(
+            C.get_smoke("mamba2-130m"), args)
+        return built["trainer"].state
+
+    put = lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+    state = jax.tree.map(put, jax.eval_shape(build))
+    batch = {"tokens": jax.ShapeDtypeStruct((m, 1, seq), jnp.int32,
+                                            sharding=one_chip)}
+    text = built["trainer"].step_fn.lower(state, batch).compile().as_text()
+    grams = re.findall(r'^\s*%?pairwise_sqdist_kernel\.\d+ = .*'
+                       r'op_name="([^"]*)"', text, flags=re.M)
+    assert len(grams) == 2                           # A and B
+    for op_name in grams:
+        parts = op_name.split("/")
+        assert "defense" in parts and "distance" in parts
+        assert parts.index("defense") < parts.index("distance")
