@@ -24,18 +24,27 @@ Three state representations are provided (DESIGN.md §6):
   * **flat** (default): the accumulators are single ``(m, d_pad)``
     matrices in one fixed ``tree_flatten`` layout (:class:`FlatLayout`,
     computed once at :func:`init_state`; :func:`unflatten_row` recovers a
-    parameter pytree for diagnostics).  The accumulate-and-reset update is
-    one fused in-place chain of column-slice adds into the buffer (the
-    reset ``where`` is the only copy; every scatter after it updates in
-    place), and the pairwise-distance pass runs on the whole buffer at
-    once — the ``safeguard_filter`` Pallas Gram kernel
-    (``backend="pallas"``, interpret mode on CPU with the package's
-    ``ref.py`` as numerics oracle), the oracle's fused f32 multiply-reduce
-    (``backend="xla"``, the choice under a sharded mesh, DESIGN.md §3), or
-    the fully fused accumulate+distance kernel streaming each d-tile
-    through VMEM exactly once (``backend="pallas_fused"``, the TPU hot
-    path — it needs the gradients flattened to one matrix first, which is
-    why it is not the CPU default);
+    parameter pytree for diagnostics).  Three backends:
+
+    - ``backend="pallas_fused"`` (the single-device TPU path that
+      ``launch.train.build_trainer`` takes): one streamed, in-place pass
+      per gradient leaf.  A Pallas kernel reads the leaf in its own dtype,
+      applies ``[reset ? 0 : acc] + g / n_good`` to A and B at once
+      through ``input_output_aliases`` and emits both accumulators'
+      per-tile Grams; the Grams are summed into distances after the
+      chain.  The layout starts every leaf on a kernel tile
+      (``make_layout(leaf_aligned=True)``; the gap columns stay zero), so
+      each leaf owns whole tiles.  The calls are chained on the aliased
+      buffers with no XLA op on A or B between them: an XLA update of a
+      slice between two calls makes the compiler keep a second copy of
+      both buffers.
+    - ``backend="pallas"``: one fused in-place chain of column-slice adds
+      into the buffer (the reset ``where`` is the only copy), then the
+      ``safeguard_filter`` Pallas Gram kernel over the whole buffer
+      (interpret mode on CPU with the package's ``ref.py`` as numerics
+      oracle); the CPU and campaign default.
+    - ``backend="xla"``: the same adds, then the oracle's fused f32
+      multiply-reduce; the choice under a sharded mesh (DESIGN.md §3).
   * **stacked** (paper-faithful reference): full stacked gradient pytrees,
     pairwise distances leaf-by-leaf via ``core.tree_utils.tree_gram``.
     Kept as the numerics oracle and for model-axis-sharded giants whose
@@ -104,27 +113,33 @@ def _pad_multiple(d: int) -> int:
     return tile
 
 
-def make_layout(grads_like) -> FlatLayout:
+def make_layout(grads_like, *, leaf_aligned: bool = False) -> FlatLayout:
     """``grads_like``: a parameter pytree (NOT worker-stacked).  The feature
     axis is padded to a kernel-tile multiple (zeros never change
     distances), so every downstream op is tile-aligned with no per-step
-    re-padding."""
+    re-padding.  ``leaf_aligned`` also rounds every leaf's offset up to that
+    tile, so each leaf owns whole tiles (the ``pallas_fused`` backend's
+    per-leaf pass); the gap columns stay zero."""
     leaves, treedef = jax.tree_util.tree_flatten(grads_like)
     if not leaves:
         raise ValueError("empty gradient pytree")
     shapes, dtypes, offsets, sizes = [], [], [], []
-    off = 0
     for leaf in leaves:
         size = 1
         for s in leaf.shape:
             size *= int(s)
         shapes.append(tuple(int(s) for s in leaf.shape))
         dtypes.append(str(jnp.dtype(leaf.dtype)))
-        offsets.append(off)
         sizes.append(size)
+    d = sum(sizes)
+    tile = _pad_multiple(d)
+    off = 0
+    for size in sizes:
+        if leaf_aligned:
+            off += (-off) % tile
+        offsets.append(off)
         off += size
-    d = off
-    d_padded = d + (-d) % _pad_multiple(d)
+    d_padded = off + (-off) % tile
     return FlatLayout(treedef=treedef, shapes=tuple(shapes),
                       dtypes=tuple(dtypes), offsets=tuple(offsets),
                       sizes=tuple(sizes), d=d, d_padded=d_padded)
@@ -132,16 +147,23 @@ def make_layout(grads_like) -> FlatLayout:
 
 def flatten_stacked(grads, layout: FlatLayout) -> jax.Array:
     """Worker-stacked pytree (leaves ``(m, ...)``) -> ``(m, d_pad)`` f32
-    matrix in the layout's column order, zero-padded feature columns."""
+    matrix in the layout's column order, zero-padded feature columns (and
+    zero gaps between leaves under a leaf-aligned layout)."""
     leaves = jax.tree_util.tree_leaves(grads)
     m = leaves[0].shape[0]
     parts = [leaf.astype(jnp.float32).reshape(m, -1) for leaf in leaves]
-    flat = parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
-    if flat.shape[1] != layout.d:
+    if sum(p.shape[1] for p in parts) != layout.d:
         raise ValueError(
-            f"gradient pytree has d={flat.shape[1]}, layout has {layout.d}")
-    if layout.d_padded != layout.d:
-        flat = jnp.pad(flat, ((0, 0), (0, layout.d_padded - layout.d)))
+            f"gradient pytree has d={sum(p.shape[1] for p in parts)}, "
+            f"layout has {layout.d}")
+    ends = layout.offsets[1:]
+    parts = [p if off + size == end else
+             jnp.pad(p, ((0, 0), (0, end - off - size)))
+             for p, off, size, end in zip(parts, layout.offsets,
+                                          layout.sizes, ends)] + parts[-1:]
+    flat = parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
+    if flat.shape[1] != layout.d_padded:
+        flat = jnp.pad(flat, ((0, 0), (0, layout.d_padded - flat.shape[1])))
     return flat
 
 
@@ -177,9 +199,9 @@ class SafeguardConfig:
     ``backend`` (flat engine only):
       * ``"pallas"`` — in-place scatter accumulate + the blocked Pallas
         Gram kernel (interpret mode off-TPU);
-      * ``"pallas_fused"`` — single streamed accumulate+distance kernel
-        (flattens the gradients to one matrix per step; the TPU hot path);
-        requires f32 accumulators;
+      * ``"pallas_fused"`` — one in-place accumulate+Gram kernel call per
+        gradient leaf, A and B together, on a leaf-aligned layout (the
+        single-device TPU path); requires f32 accumulators;
       * ``"xla"`` — in-place scatter accumulate + the oracle's fused f32
         multiply-reduce (``kernels/safeguard_filter/ref.py``);
         use under a sharded mesh where a single-device kernel cannot be
@@ -280,7 +302,8 @@ def init_state(cfg: SafeguardConfig, grads_like) -> SafeguardState:
         if cfg.use_sketch:
             shape, dtype = (cfg.m, cfg.sketch_reps * cfg.sketch_k), jnp.float32
         else:
-            layout = make_layout(grads_like)
+            layout = make_layout(
+                grads_like, leaf_aligned=cfg.backend == "pallas_fused")
             shape, dtype = (cfg.m, layout.d_padded), cfg.acc_dtype
         A = jnp.zeros(shape, dtype) if cfg.mode == "double" else None
         B = jnp.zeros(shape, dtype)
@@ -387,23 +410,30 @@ def _flat_sqdist(buf, cfg: SafeguardConfig):
     return sf_ref.pairwise_sqdist(buf)
 
 
-def _flat_update(acc, grads, gflat, reset, scale, cfg: SafeguardConfig,
+def _flat_update(acc, grads, reset, scale, cfg: SafeguardConfig,
                  layout: FlatLayout):
-    """One accumulator's flat-engine update -> (new_acc, sqdist).
-
-    ``gflat`` is the flattened gradient matrix, materialized by the caller
-    only for the ``pallas_fused`` backend (``None`` otherwise).  The fused
-    kernel runs under the scope ``accumulate``; otherwise the accumulate
-    runs under ``accumulate`` and the distance pass under ``distance``."""
-    if gflat is not None:
-        from repro.kernels.safeguard_filter import fused_accumulate_sqdist
-        with jax.named_scope("accumulate"):
-            return fused_accumulate_sqdist(acc, gflat, reset, scale,
-                                           interpret=not _on_tpu())
+    """One accumulator's update -> (new_acc, sqdist), the accumulate under
+    the scope ``accumulate`` and the distance pass under ``distance``."""
     with jax.named_scope("accumulate"):
         new = _accumulate_flat(acc, grads, reset, scale, layout)
     with jax.named_scope("distance"):
         return new, _flat_sqdist(new, cfg)
+
+
+def _fused_update(accs, grads, resets, scale, layout: FlatLayout):
+    """``backend="pallas_fused"``: every accumulator of ``accs`` (A and B,
+    or B alone) updated in place by one kernel call per gradient leaf, in
+    ``tree_leaves`` order, each call also emitting the updated tiles'
+    Grams; the Grams are summed into squared distances after the chain.
+    Returns (new_accs, sqdists)."""
+    from repro.kernels.safeguard_filter import (fused_accumulate_sqdist,
+                                                sqdist_from_tile_grams)
+    with jax.named_scope("accumulate"):
+        accs, grams = fused_accumulate_sqdist(
+            jax.tree_util.tree_leaves(grads), layout.offsets, accs, resets,
+            scale, align=_pad_multiple(layout.d), interpret=not _on_tpu())
+    with jax.named_scope("distance"):
+        return accs, tuple(sqdist_from_tile_grams(g) for g in grams)
 
 
 # --------------------------------------------------------------------------
@@ -466,15 +496,21 @@ def safeguard_step(state: SafeguardState, grads, cfg: SafeguardConfig,
                         else None)
     elif cfg.engine == "flat":
         layout = state.layout
-        with jax.named_scope("accumulate"):
-            gflat = (flatten_stacked(grads, layout)
-                     if cfg.backend == "pallas_fused" else None)
-        B, sqdist_B = _flat_update(state.B, grads, gflat, reset_B,
-                                   inv_ngood, cfg, layout)
         A, sqdist_A = None, None
-        if cfg.mode == "double":
-            A, sqdist_A = _flat_update(state.A, grads, gflat, reset_A,
-                                       inv_ngood, cfg, layout)
+        if cfg.backend == "pallas_fused":
+            if cfg.mode == "double":
+                (A, B), (sqdist_A, sqdist_B) = _fused_update(
+                    (state.A, state.B), grads,
+                    jnp.stack([reset_A, reset_B]), inv_ngood, layout)
+            else:
+                (B,), (sqdist_B,) = _fused_update(
+                    (state.B,), grads, reset_B, inv_ngood, layout)
+        else:
+            B, sqdist_B = _flat_update(state.B, grads, reset_B, inv_ngood,
+                                       cfg, layout)
+            if cfg.mode == "double":
+                A, sqdist_A = _flat_update(state.A, grads, reset_A,
+                                           inv_ngood, cfg, layout)
         if acc_sharding is not None:
             with jax.named_scope("accumulate"):
                 B = jax.lax.with_sharding_constraint(B, acc_sharding)

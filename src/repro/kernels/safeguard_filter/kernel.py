@@ -14,11 +14,12 @@ through VMEM:
 Two entry points:
 
   * ``pairwise_sqdist_kernel`` — distances of an existing buffer;
-  * ``fused_accumulate_sqdist_kernel`` — the safeguard hot path: each
-    d-tile additionally applies the windowed accumulate-and-reset update
-    ``acc <- [reset ? 0 : acc] + g / n_good`` *in place*
-    (``input_output_aliases``) before forming its Gram, so the O(m d)
-    state is streamed exactly once per step.
+  * ``fused_accumulate_sqdist_kernel`` — the safeguard hot path, one
+    gradient leaf per call: each of the leaf's d-tiles applies the
+    windowed accumulate-and-reset update ``acc <- [reset ? 0 : acc] + g /
+    n_good`` to one or two accumulators *in place*
+    (``input_output_aliases``) and forms each updated tile's Gram, so the
+    O(m d) state is streamed exactly once per step.
 
 Every block spans all m worker rows (no sublane padding, see ``ops.py``);
 ``block_d`` is a multiple of the 128-wide lane dimension so each tile is
@@ -45,7 +46,7 @@ def _gram_tile(a):
     return jnp.concatenate(cols, axis=1)
 
 
-def _sqdist_from_tile_grams(partial):
+def sqdist_from_tile_grams(partial):
     """(nd, m, m) per-tile Gram partials -> (m, m) f32 squared distances.
 
     The partials are summed here, by XLA's tree reduction, and not into
@@ -78,54 +79,74 @@ def pairwise_sqdist_kernel(a, *, block_d: int = 512,
         interpret=interpret,
         name="pairwise_sqdist_kernel",
     )(a)
-    return _sqdist_from_tile_grams(partial)
+    return sqdist_from_tile_grams(partial)
 
 
-def _fused_kernel(reset_ref, scale_ref, acc_ref, g_ref, newacc_ref,
-                  out_ref):
-    # select, NOT multiply-by-(1-reset): a Byzantine inf/NaN in the old
-    # accumulator must be zeroed by the window reset (inf * 0 = NaN)
-    a = acc_ref[...].astype(jnp.float32)
-    a = jnp.where(reset_ref[0] != 0, jnp.zeros_like(a), a)
-    new = a + g_ref[...].astype(jnp.float32) * scale_ref[0]
-    newacc_ref[...] = new
-    out_ref[0] = _gram_tile(new)
+def _accumulate_kernel(flags_ref, scale_ref, g_ref, *refs, size: int,
+                       block_d: int):
+    """One d-tile of one gradient leaf: ``new = [reset ? 0 : acc] + g *
+    scale`` for each accumulator, in place, and each updated tile's Gram.
+    Columns past the leaf's ``size`` (the last tile's tail: the layout's
+    zero gap, or whatever a partial block read) keep the accumulator's
+    values."""
+    n = len(refs) // 3
+    accs, news, grams = refs[:n], refs[n:2 * n], refs[2 * n:]
+    col = (pl.program_id(0) * block_d
+           + jax.lax.broadcasted_iota(jnp.int32, (1, block_d), 1))
+    inside = col < size
+    step = jnp.where(inside, g_ref[...].astype(jnp.float32), 0.0) \
+        * scale_ref[0]
+    for k in range(n):
+        a = accs[k][...]
+        # select, NOT multiply-by-(1-reset): a Byzantine inf/NaN in the old
+        # accumulator must be zeroed by the window reset (inf * 0 = NaN)
+        new = jnp.where(flags_ref[k] != 0, jnp.zeros_like(a), a) + step
+        new = jnp.where(inside, new, a)
+        news[k][...] = new
+        grams[k][0] = _gram_tile(new)
 
 
-@functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
-def fused_accumulate_sqdist_kernel(acc, g, reset, scale, *,
-                                   block_d: int = 512,
-                                   interpret: bool = True):
-    """One streamed pass of the safeguard update (DESIGN.md §6).
+@functools.partial(jax.jit,
+                   static_argnames=("offset", "block_d", "interpret"))
+def fused_accumulate_sqdist_kernel(g, accs, resets, scale, *, offset: int,
+                                   block_d: int, interpret: bool = True):
+    """One gradient leaf's streamed pass of the safeguard update
+    (DESIGN.md §6), for one or two accumulators at once.
 
-    acc, g: (m, d) f32 with d divisible by block_d; reset: (1,) int32;
-    scale: (1,) f32 (= 1 / n_good).  Returns (new_acc, sqdist) where
-    new_acc aliases acc's buffer and sqdist is the (m, m) f32 pairwise
-    squared-distance matrix of the UPDATED accumulators.
+    g: (m, size) in the leaf's own dtype; accs: tuple of (m, d_pad) f32
+    buffers holding the leaf at columns ``offset:offset + size``, with
+    ``offset`` a multiple of ``block_d`` and the leaf's last tile inside
+    the buffer; resets: (len(accs),) int32 window-reset flags; scale: (1,)
+    f32 (= 1 / n_good).  The grid runs over the leaf's tiles; the last is
+    masked in the kernel, so the gradient is never padded.
+
+    Returns ``(new_accs, tile_grams)``: each new accumulator aliases its
+    input buffer (columns outside the leaf's tiles are not touched) and
+    each ``tile_grams`` entry is the ``(n_tiles, m, m)`` f32 Grams of the
+    updated tiles, to be summed by :func:`sqdist_from_tile_grams`.
     """
-    m, d = acc.shape
-    assert g.shape == (m, d), (acc.shape, g.shape)
-    assert d % block_d == 0, (d, block_d)
-    nd = d // block_d
-    new, partial = pl.pallas_call(
-        _fused_kernel,
-        grid=(nd,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),            # reset
-            pl.BlockSpec(memory_space=pltpu.SMEM),            # scale
-            pl.BlockSpec((m, block_d), lambda i: (0, i)),     # acc tile
-            pl.BlockSpec((m, block_d), lambda i: (0, i)),     # grad tile
-        ],
-        out_specs=[
-            pl.BlockSpec((m, block_d), lambda i: (0, i)),     # new acc
-            pl.BlockSpec((1, m, m), lambda i: (i, 0, 0)),     # tile Gram
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((m, d), jnp.float32),
-            jax.ShapeDtypeStruct((nd, m, m), jnp.float32),
-        ],
-        input_output_aliases={2: 0},
+    m, size = g.shape
+    n = len(accs)
+    assert offset % block_d == 0, (offset, block_d)
+    nt = pl.cdiv(size, block_d)
+    base = offset // block_d
+    for a in accs:
+        assert a.shape[0] == m and a.dtype == jnp.float32, (a.shape, a.dtype)
+        assert (base + nt) * block_d <= a.shape[1], (offset, size, a.shape)
+    tile = pl.BlockSpec((m, block_d), lambda i: (0, base + i))
+    out = pl.pallas_call(
+        functools.partial(_accumulate_kernel, size=size, block_d=block_d),
+        grid=(nt,),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),      # resets
+                  pl.BlockSpec(memory_space=pltpu.SMEM),      # scale
+                  pl.BlockSpec((m, block_d), lambda i: (0, i))]  # grad
+        + [tile] * n,                                         # accumulators
+        out_specs=[tile] * n
+        + [pl.BlockSpec((1, m, m), lambda i: (i, 0, 0))] * n,  # tile Grams
+        out_shape=[jax.ShapeDtypeStruct(a.shape, jnp.float32) for a in accs]
+        + [jax.ShapeDtypeStruct((nt, m, m), jnp.float32)] * n,
+        input_output_aliases={3 + k: k for k in range(n)},
         interpret=interpret,
         name="fused_accumulate_sqdist_kernel",
-    )(reset, scale, acc, g)
-    return new, _sqdist_from_tile_grams(partial)
+    )(resets, scale, g, *accs)
+    return tuple(out[:n]), tuple(out[n:])

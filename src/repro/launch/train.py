@@ -102,7 +102,9 @@ def build_trainer(cfg, args, *, mesh=None) -> Trainer:
     ``data`` shard) state and batches are placed by the rules of
     ``launch.specs`` and the safeguard uses the shardable XLA distance pass
     (DESIGN.md §3); without one everything lives on the default device and
-    the Pallas Gram kernel runs."""
+    the safeguard's accumulators and their Grams are updated by one
+    in-place Pallas pass per gradient leaf (``pallas_fused``, DESIGN.md
+    §6)."""
     m, n_byz = args.workers, args.byz
     if args.batch % m:
         raise SystemExit("--batch must be divisible by --workers")
@@ -110,7 +112,8 @@ def build_trainer(cfg, args, *, mesh=None) -> Trainer:
 
     attack = atk_lib.make_registry()[args.attack]
     defense = build_defense(args.defense, m, n_byz, args,
-                            backend="pallas" if mesh is None else "xla")
+                            backend="pallas_fused" if mesh is None
+                            else "xla")
 
     opt = make_optimizer(TrainConfig(lr=args.lr, momentum=args.momentum,
                                      optimizer=args.optimizer))

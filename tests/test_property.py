@@ -140,14 +140,16 @@ def test_near_duplicate_rows_never_nan_any_sqdist_path(mag, m, seed):
     rows = base + 1e-6 * mag * jax.random.normal(
         jax.random.fold_in(key, 1), (m, d))
     from repro.kernels.safeguard_filter import (fused_accumulate_sqdist,
-                                                pairwise_sqdist)
+                                                pairwise_sqdist,
+                                                sqdist_from_tile_grams)
     from repro.kernels.safeguard_filter import ref as sf_ref
     outs = {
         "pallas": pairwise_sqdist(rows),
         "ref": sf_ref.pairwise_sqdist(rows),
         "tree": tu.tree_pairwise_sqdist({"x": rows}),
-        "fused": fused_accumulate_sqdist(
-            jnp.zeros_like(rows), rows, 0, 1.0)[1],
+        "fused": sqdist_from_tile_grams(fused_accumulate_sqdist(
+            [rows], (0,), (jnp.zeros_like(rows),), 0, 1.0,
+            align=128)[1][0]),
         "sketch": sk.sketch_pairwise_sqdist(
             sk.sketch_tree({"x": rows}, k=128, reps=2)),
     }

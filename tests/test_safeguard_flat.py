@@ -12,7 +12,8 @@ import pytest
 from repro.core import SafeguardConfig, init_state, safeguard_step
 from repro.core import attacks as atk
 from repro.core import safeguard as sg
-from repro.kernels.safeguard_filter import fused_accumulate_sqdist
+from repro.kernels.safeguard_filter import (fused_accumulate_sqdist,
+                                            sqdist_from_tile_grams)
 from repro.kernels.safeguard_filter import ref as sf_ref
 
 M = 10
@@ -110,8 +111,9 @@ def test_sqdist_producers_clamp_at_zero(mag, rng):
         "pallas": pairwise_sqdist(rows),
         "ref": sf_ref.pairwise_sqdist(rows),
         "tree": tu.tree_pairwise_sqdist({"x": rows}),
-        "fused": fused_accumulate_sqdist(
-            jnp.zeros_like(rows), rows, 0, 1.0)[1],
+        "fused": sqdist_from_tile_grams(fused_accumulate_sqdist(
+            [rows], (0,), (jnp.zeros_like(rows),), 0, 1.0,
+            align=128)[1][0]),
         "sketch": sk.sketch_pairwise_sqdist(
             sk.sketch_tree({"x": rows}, k=128, reps=2)),
     }
@@ -214,46 +216,132 @@ def test_layout_static_and_round_trip():
         np.asarray(a), np.asarray(b[4]), atol=1e-6), back, g)
 
 
+def test_leaf_aligned_layout_round_trip_and_row_norms():
+    """The ``pallas_fused`` layout starts every leaf on a tile boundary:
+    rows round-trip through ``unflatten_row``, the gap columns stay zero,
+    and per-leaf row norms of ``B`` read by ``offsets``/``sizes`` slices
+    equal the stacked engine's per-leaf norms after one step."""
+    lay = sg.make_layout(PARAMS, leaf_aligned=True)
+    tile = sg._pad_multiple(lay.d)
+    assert lay.sizes == sg.make_layout(PARAMS).sizes
+    assert all(off % tile == 0 for off in lay.offsets)
+    assert lay.d_padded % tile == 0
+    assert lay.d_padded >= lay.offsets[-1] + lay.sizes[-1]
+    g = honest_grads(jax.random.PRNGKey(3))
+    flat = sg.flatten_stacked(g, lay)
+    assert flat.shape == (M, lay.d_padded)
+    back = sg.unflatten_row(flat[4], lay)
+    jax.tree.map(lambda a, b: np.testing.assert_array_equal(
+        np.asarray(a), np.asarray(b[4])), back, g)
+
+    cfg = dict(m=M, T0=20, T1=60, threshold_floor=0.5)
+    fused = SafeguardConfig(backend="pallas_fused", **cfg)
+    stacked = SafeguardConfig(engine="stacked", **cfg)
+    st_f, _, _ = safeguard_step(init_state(fused, PARAMS), g, fused)
+    st_s, _, _ = safeguard_step(init_state(stacked, PARAMS), g, stacked)
+    assert st_f.layout == lay
+    B = np.asarray(st_f.B)
+    covered = np.zeros(lay.d_padded, bool)
+    for off, size in zip(lay.offsets, lay.sizes):
+        covered[off:off + size] = True
+    assert not B[:, ~covered].any()
+    norms = [np.sqrt((B[:, o:o + s] ** 2).sum(axis=1))
+             for o, s in zip(lay.offsets, lay.sizes)]
+    want = [np.sqrt((np.asarray(leaf).reshape(M, -1) ** 2).sum(axis=1))
+            for leaf in jax.tree_util.tree_leaves(st_s.B)]
+    np.testing.assert_allclose(norms, want, rtol=1e-6)
+
+
+def _aligned(m, shapes, key, dtype=jnp.float32):
+    """A leaf-aligned layout of leaves of ``shapes``, and random
+    worker-stacked gradients and accumulators on it (zero gap columns, as
+    the layout keeps them)."""
+    params = {f"p{i}": jnp.zeros(s) for i, s in enumerate(shapes)}
+    lay = sg.make_layout(params, leaf_aligned=True)
+    ks = jax.random.split(key, 3 * len(shapes))
+    stack = lambda k0: jax.tree.map(
+        lambda p, k: jax.random.normal(k, (m,) + p.shape), params,
+        dict(zip(params, ks[k0::3])))
+    g = jax.tree.map(lambda x: x.astype(dtype), stack(0))
+    accs = (sg.flatten_stacked(stack(1), lay), sg.flatten_stacked(stack(2),
+                                                                   lay))
+    return lay, g, accs
+
+
+def _fused(lay, g, accs, resets, scale):
+    new, grams = fused_accumulate_sqdist(
+        jax.tree_util.tree_leaves(g), lay.offsets, accs, jnp.asarray(resets),
+        scale, align=sg._pad_multiple(lay.d))
+    return new, [sqdist_from_tile_grams(t) for t in grams]
+
+
 @pytest.mark.parametrize("m,d", [(10, 777), (8, 1024), (3, 50)])
 @pytest.mark.parametrize("reset", [0, 1])
 def test_fused_kernel_matches_oracle(m, d, reset, rng):
-    k1, k2 = jax.random.split(rng)
-    acc = jax.random.normal(k1, (m, d))
-    g = jax.random.normal(k2, (m, d))
-    new, sq = fused_accumulate_sqdist(acc, g, reset, 0.125)
-    ref_new, ref_sq = sf_ref.fused_accumulate_sqdist(acc, g, reset, 0.125)
-    np.testing.assert_allclose(np.asarray(new), np.asarray(ref_new),
-                               atol=1e-5)
-    np.testing.assert_allclose(np.asarray(sq), np.asarray(ref_sq),
-                               atol=1e-3 * max(d, 1))
+    """A and B, under each of the four combinations of their reset flags
+    (``reset`` is A's, B's takes both values), against the oracle on the
+    whole buffer; the gradient is read in bf16, as the step passes it."""
+    lay, g, accs = _aligned(m, [(d,), (7, 3)], rng, jnp.bfloat16)
+    gflat = sg.flatten_stacked(g, lay)
+    for reset_B in (0, 1):
+        new, sq = _fused(lay, g, accs, [reset, reset_B], 0.125)
+        for acc, r, got, got_sq in zip(accs, (reset, reset_B), new, sq):
+            ref_new, ref_sq = sf_ref.fused_accumulate_sqdist(acc, gflat, r,
+                                                             0.125)
+            np.testing.assert_allclose(np.asarray(got), np.asarray(ref_new),
+                                       atol=1e-5)
+            np.testing.assert_allclose(np.asarray(got_sq),
+                                       np.asarray(ref_sq),
+                                       atol=1e-3 * max(d, 1))
 
 
 def test_fused_kernel_reset_zeroes_nonfinite_accumulator(rng):
     """The window reset must be a select, not multiply-by-(1-reset): a
     Byzantine inf/NaN in the old accumulator has to vanish at the reset
     (inf * 0 = NaN would poison distances forever)."""
-    acc = jnp.ones((8, 256)).at[2].set(jnp.inf).at[3].set(jnp.nan)
-    g = jnp.ones((8, 256))
-    new, sq = fused_accumulate_sqdist(acc, g, 1, 0.5)
-    ref_new, ref_sq = sf_ref.fused_accumulate_sqdist(acc, g, 1, 0.5)
-    assert bool(jnp.isfinite(new).all()) and bool(jnp.isfinite(sq).all())
-    np.testing.assert_allclose(np.asarray(new), np.asarray(ref_new))
-    np.testing.assert_allclose(np.asarray(sq), np.asarray(ref_sq),
-                               atol=1e-3)
+    lay, g, (A, B) = _aligned(8, [(256,)], rng)
+    A = A.at[2].set(jnp.inf).at[3].set(jnp.nan)
+    B = B.at[1].set(jnp.nan).at[5].set(-jnp.inf)
+    new, sq = _fused(lay, g, (A, B), [1, 1], 0.5)
+    gflat = sg.flatten_stacked(g, lay)
+    for acc, got, got_sq in zip((A, B), new, sq):
+        ref_new, ref_sq = sf_ref.fused_accumulate_sqdist(acc, gflat, 1, 0.5)
+        assert bool(jnp.isfinite(got).all()) and \
+            bool(jnp.isfinite(got_sq).all())
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref_new))
+        np.testing.assert_allclose(np.asarray(got_sq), np.asarray(ref_sq),
+                                   atol=1e-3)
 
 
-def test_fused_kernel_explicit_block_not_dividing(rng):
-    """An explicit block_d that does not divide the lane-padded d must be
-    handled by padding, not an assert."""
-    k1, k2 = jax.random.split(rng)
-    acc = jax.random.normal(k1, (8, 1280))
-    g = jax.random.normal(k2, (8, 1280))
-    new, sq = fused_accumulate_sqdist(acc, g, 0, 0.25, block_d=512)
-    ref_new, ref_sq = sf_ref.fused_accumulate_sqdist(acc, g, 0, 0.25)
-    np.testing.assert_allclose(np.asarray(new), np.asarray(ref_new),
-                               atol=1e-5)
-    np.testing.assert_allclose(np.asarray(sq), np.asarray(ref_sq),
-                               atol=1.3)
+@pytest.mark.parametrize("mode", ["double", "single"])
+def test_fused_kernel_explicit_block_not_dividing(mode, rng):
+    """A leaf whose size is no tile multiple: the kernel masks its last
+    tile, so the leaf's columns match the oracle and every other column
+    (the gap after it, the other leaves') keeps its bits, here random
+    values; ``single`` runs the same kernel on B alone."""
+    m, size, off, align = 8, 1000, 1024, 512
+    k1, k2, k3 = jax.random.split(rng, 3)
+    g = jax.random.normal(k1, (m, 10, 100))
+    accs = (jax.random.normal(k2, (m, 3072)),
+            jax.random.normal(k3, (m, 3072)))
+    accs = accs if mode == "double" else accs[1:]
+    new, grams = fused_accumulate_sqdist([g], (off,), accs,
+                                         jnp.zeros(len(accs), bool), 0.25,
+                                         align=align)
+    assert [t.shape for t in grams] == [(2, m, m)] * len(accs)
+    for acc, got, t in zip(accs, new, grams):
+        got, acc = np.asarray(got), np.asarray(acc)
+        ref_new, _ = sf_ref.fused_accumulate_sqdist(
+            acc[:, off:off + size], g.reshape(m, -1), 0, 0.25)
+        np.testing.assert_allclose(got[:, off:off + size],
+                                   np.asarray(ref_new), atol=1e-5)
+        np.testing.assert_array_equal(got[:, :off], acc[:, :off])
+        np.testing.assert_array_equal(got[:, off + size:],
+                                      acc[:, off + size:])
+        # the Grams are those of the leaf's two whole tiles
+        tiles = got[:, off:off + 2 * align]
+        np.testing.assert_allclose(np.asarray(t.sum(axis=0)),
+                                   tiles @ tiles.T, rtol=1e-5, atol=1e-2)
 
 
 def test_flat_state_shapes_and_dtype():

@@ -8,7 +8,9 @@ described inside a fixture, never at import, so every pytest-xdist worker
 collects the same tests and only the worker given this file loads the TPU
 compiler.  All such compiles live in this one file."""
 
+import functools
 import re
+from unittest import mock
 
 import jax
 import jax.numpy as jnp
@@ -45,44 +47,72 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _smoke_d_pad() -> int:
-    return sg.make_layout(T.init_abstract(cs.model_config())).d_padded
+def _smoke_layout(leaf_aligned: bool = False) -> sg.FlatLayout:
+    return sg.make_layout(T.init_abstract(cs.model_config()),
+                          leaf_aligned=leaf_aligned)
 
 
-@pytest.mark.parametrize("kernel", ["pairwise_sqdist",
-                                    "fused_accumulate_sqdist"])
-def test_kernel_compiles_for_v5e_at_smoke_width(one_chip, kernel):
-    buf = jax.ShapeDtypeStruct((cs.M, _smoke_d_pad()), jnp.float32,
-                               sharding=one_chip)
-    if kernel == "pairwise_sqdist":
-        fn = lambda a: pairwise_sqdist(a, block_d=None, interpret=False)
-        args = (buf,)
-    else:
-        scalar = lambda dt: jax.ShapeDtypeStruct((), dt, sharding=one_chip)
-        fn = lambda a, g, r, s: fused_accumulate_sqdist(a, g, r, s,
-                                                        interpret=False)
-        args = (buf, buf, scalar(jnp.int32), scalar(jnp.float32))
-    compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-    # rows are never padded: the kernel reads the buffer in place
-    assert compiled.memory_analysis().temp_size_in_bytes < buf.size
-
-
-def test_smoke_step_compiles_for_v5e_and_fits(one_chip, monkeypatch):
-    monkeypatch.setattr(sg, "_on_tpu", lambda: True)
+def _compile_smoke_step(one_chip, backend: str):
+    """The donated smoke step of ``chip_smoke.py``, built through
+    ``build_trainer`` with the safeguard on ``backend``, compiled for one
+    v5e chip.  Traced, so the full-width state is only shapes."""
     built = {}
+    build_defense = cs.train_lib.build_defense
 
     def build():
-        # traced, so the full-width state is only shapes, never allocated
-        built["trainer"] = cs.train_lib.build_trainer(cs.model_config(),
-                                                      cs.smoke_args())
+        with mock.patch.object(sg, "_on_tpu", lambda: True), \
+                mock.patch.object(cs.train_lib, "build_defense",
+                                  lambda *a, **kw: build_defense(
+                                      *a, **{**kw, "backend": backend})):
+            built["trainer"] = cs.train_lib.build_trainer(cs.model_config(),
+                                                          cs.smoke_args())
         return built["trainer"].state
 
     put = lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
     state = jax.tree.map(put, jax.eval_shape(build))
     batch = {"tokens": jax.ShapeDtypeStruct(
         (cs.M, cs.PER_WORKER_BATCH, cs.SEQ), jnp.int32, sharding=one_chip)}
-    compiled = built["trainer"].step_fn.lower(state, batch).compile()
+    with mock.patch.object(sg, "_on_tpu", lambda: True):
+        return built["trainer"].step_fn.lower(state, batch).compile()
+
+
+@pytest.fixture(scope="module")
+def smoke_step(one_chip):
+    """``smoke_step(backend)``: the compiled smoke step, once per backend."""
+    return functools.cache(functools.partial(_compile_smoke_step, one_chip))
+
+
+@pytest.mark.parametrize("kernel", ["pairwise_sqdist",
+                                    "fused_accumulate_sqdist"])
+def test_kernel_compiles_for_v5e_at_smoke_width(one_chip, kernel):
+    leaf_aligned = kernel == "fused_accumulate_sqdist"
+    lay = _smoke_layout(leaf_aligned)
+    buf = jax.ShapeDtypeStruct((cs.M, lay.d_padded), jnp.float32,
+                               sharding=one_chip)
+    if kernel == "pairwise_sqdist":
+        fn = lambda a: pairwise_sqdist(a, block_d=None, interpret=False)
+        compiled = jax.jit(fn).lower(buf).compile()
+    else:
+        leaves = [jax.ShapeDtypeStruct((cs.M,) + shape, jnp.dtype(dt),
+                                       sharding=one_chip)
+                  for shape, dt in zip(lay.shapes, lay.dtypes)]
+        resets = jax.ShapeDtypeStruct((2,), jnp.int32, sharding=one_chip)
+        scale = jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
+        fn = lambda gs, a, b, r, s: fused_accumulate_sqdist(
+            gs, lay.offsets, (a, b), r, s,
+            align=sg._pad_multiple(lay.d), interpret=False)
+        compiled = jax.jit(fn, donate_argnums=(1, 2)).lower(
+            leaves, buf, buf, resets, scale).compile()
+        mem = compiled.memory_analysis()
+        # A and B are updated in place: their outputs are their arguments
+        assert mem.alias_size_in_bytes >= 2 * buf.size * 4
+    assert "tpu_custom_call" in compiled.as_text()
+    # rows are never padded: the kernel reads the buffer in place
+    assert compiled.memory_analysis().temp_size_in_bytes < buf.size
+
+
+def test_smoke_step_compiles_for_v5e_and_fits(smoke_step):
+    compiled = smoke_step("pallas_fused")     # build_trainer's on one chip
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
     # the state is donated: the outputs live in the arguments' buffers
@@ -94,12 +124,32 @@ def test_smoke_step_compiles_for_v5e_and_fits(one_chip, monkeypatch):
     assert live < HBM_BYTES
 
 
+def test_fused_smoke_step_has_no_gram_kernel_or_accumulator_copy(
+        smoke_step):
+    """``pallas_fused`` updates A and B in place and forms their Grams in
+    the same pass: the step runs no separate Gram kernel, copies no
+    ``f32[m, d_pad]`` accumulator, and needs fewer temporaries than the
+    ``pallas`` backend's scatter-and-Gram step."""
+    fused, scatter = smoke_step("pallas_fused"), smoke_step("pallas")
+    text = fused.as_text()
+    kernels = lambda name: re.findall(
+        rf"^\s*%?{name}\.\d+ = .* custom-call\(", text, flags=re.M)
+    assert kernels("fused_accumulate_sqdist_kernel")
+    assert not kernels("pairwise_sqdist_kernel")
+    d_pad = _smoke_layout(leaf_aligned=True).d_padded
+    copies = re.findall(rf"= f32\[{cs.M},{d_pad}\]\S* copy\(", text)
+    assert not copies
+    assert (fused.memory_analysis().temp_size_in_bytes
+            < scatter.memory_analysis().temp_size_in_bytes)
+
+
 def test_scoped_smoke_step_names_its_gram_kernel_for_v5e(one_chip,
                                                           monkeypatch):
     """The v5e step of a smoke safeguard run keeps the Gram kernel's
-    instruction name, ``pairwise_sqdist_kernel.N``, by which a device
-    trace finds the kernel, and its ``op_name`` lies under the defense's
-    distance pass."""
+    instruction name, ``fused_accumulate_sqdist_kernel.N``, by which a
+    device trace finds the kernel: one call per gradient leaf, which
+    updates A and B and forms their Grams, its ``op_name`` under the
+    defense's accumulate pass."""
     from repro import configs as C
     from repro.launch import train as train_lib
     m, seq = 4, 256
@@ -120,10 +170,11 @@ def test_scoped_smoke_step_names_its_gram_kernel_for_v5e(one_chip,
     batch = {"tokens": jax.ShapeDtypeStruct((m, 1, seq), jnp.int32,
                                             sharding=one_chip)}
     text = built["trainer"].step_fn.lower(state, batch).compile().as_text()
-    grams = re.findall(r'^\s*%?pairwise_sqdist_kernel\.\d+ = .*'
+    grams = re.findall(r'^\s*%?fused_accumulate_sqdist_kernel\.\d+ = .*'
                        r'op_name="([^"]*)"', text, flags=re.M)
-    assert len(grams) == 2                           # A and B
+    assert len(grams) == len(built["trainer"].state.defense_state.layout
+                             .sizes)                  # one per leaf
     for op_name in grams:
         parts = op_name.split("/")
-        assert "defense" in parts and "distance" in parts
-        assert parts.index("defense") < parts.index("distance")
+        assert "defense" in parts and "accumulate" in parts
+        assert parts.index("defense") < parts.index("accumulate")
