@@ -41,6 +41,12 @@ class ModelConfig:
     attn: str = "full"             # full | sliding
     window: int = 0                # sliding-window size (attn == "sliding")
     attn_logit_softcap: float = 0.0
+    attn_scale: float = 0.0        # 0 -> 1 / sqrt(head_dim)
+
+    # Granite's muP multipliers; 1.0 leaves the arithmetic as it is
+    embed_multiplier: float = 1.0      # token embeddings times this
+    residual_multiplier: float = 1.0   # every residual branch times this
+    logits_divisor: float = 1.0        # output logits divided by this
 
     # modality frontend stub (vlm/audio): inputs are precomputed embeddings
     embed_stub: bool = False
@@ -52,8 +58,12 @@ class ModelConfig:
     d_expert: int = 0
     first_k_dense: int = 0         # deepseek-v2: first layer(s) use dense FFN
     d_ff_dense: int = 0            # dense-FFN width for those layers
-    capacity_factor: float = 1.25
     router_aux_coef: float = 0.01
+    # the routed experts this model holds: ``n_held_experts`` of them from
+    # ``first_held_expert`` on (one chip's share of an expert-parallel
+    # layer); 0 holds all ``n_experts``
+    first_held_expert: int = 0
+    n_held_experts: int = 0
 
     # MLA (deepseek-v2)
     use_mla: bool = False
@@ -85,6 +95,12 @@ class ModelConfig:
             object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
 
     @property
+    def held_experts(self) -> range:
+        """Ids of the routed experts whose weights this model holds."""
+        n = self.n_held_experts or self.n_experts
+        return range(self.first_held_expert, self.first_held_expert + n)
+
+    @property
     def d_inner(self) -> int:        # ssm inner width
         return self.expand * self.d_model
 
@@ -108,7 +124,8 @@ class ModelConfig:
 
     def active_param_count(self) -> int:
         """Activated parameters per token (MoE: routed top_k of n_experts,
-        shared experts and everything else fully active)."""
+        of the held ones in expectation; shared experts and everything
+        else fully active)."""
         if self.n_experts == 0:
             return self.param_count()
         import math

@@ -5,8 +5,9 @@ Strategy (DESIGN.md §3): FSDP x TP —
   * every parameter leaf shards its largest eligible dim over ``model``
     (tensor parallel) and the next eligible dim over the data axes (fully
     sharded data parallel), leading layer-stack axes excluded;
-  * MoE expert tensors override the heuristic: the expert dim goes to
-    ``model`` (expert parallelism), the feature dim to data;
+  * MoE expert tensors override the heuristic: the held-expert dim goes
+    to ``model``, the feature dim to data (storage: the layer gathers its
+    held experts whole for its grouped matmuls);
   * stacked per-worker gradients put the worker axis on the data axes and
     keep only the ``model`` assignments of the underlying parameter — the
     worker axis *is* the data axis;
@@ -115,7 +116,10 @@ def param_pspec(path, leaf, mesh) -> P:
                 break
         return P(*spec)
 
-    # MoE experts: (stack, E, d, f) / (stack, E, f, d) — expert parallel
+    # MoE experts: (stack, G, d, f) / (stack, G, f, d) — the held-expert
+    # axis over ``model`` at rest only: a grouped matmul cannot split its
+    # groups, so ``layers.moe_apply`` gathers the layer's held experts
+    # whole, and each data shard runs its own workers' matmuls
     if "/moe/" in f"/{pstr}/" and pstr.rsplit("/", 1)[-1] in (
             "w_gate", "w_up", "w_down") and len(shape) - skip == 3:
         E, a, b = shape[skip], shape[skip + 1], shape[skip + 2]

@@ -14,6 +14,7 @@ For the at-scale (256/512-chip) lowering of the same step, use
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
 
@@ -91,6 +92,17 @@ def _placed(batches, mesh, m: int):
             is_leaf=lambda x: isinstance(x, P)))
 
 
+def _under_mesh(fn, mesh):
+    """``fn`` traced with ``mesh`` as the context mesh, where the model's
+    per-worker loops (``models.layers._lanes_in_turn``) find its worker
+    axes."""
+    @functools.wraps(fn)
+    def traced(*args):
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            return fn(*args)
+    return traced
+
+
 def build_trainer(cfg, args, *, mesh=None) -> Trainer:
     """The run's wiring: config -> defense -> ``make_train_step`` ->
     :class:`Trainer`, shared by :func:`main` and ``chip_smoke.py``.
@@ -117,7 +129,8 @@ def build_trainer(cfg, args, *, mesh=None) -> Trainer:
 
     opt = make_optimizer(TrainConfig(lr=args.lr, momentum=args.momentum,
                                      optimizer=args.optimizer))
-    loss = lambda p, b: T.loss_fn(p, cfg, b)
+    # the step reports each worker's held routed-expert load, if any
+    loss = lambda p, b: T.loss_and_load(p, cfg, b)
 
     params = T.init_params(cfg, jax.random.PRNGKey(args.seed))
     state = init_train_state(params, opt, defense=defense, attack=attack,
@@ -132,7 +145,10 @@ def build_trainer(cfg, args, *, mesh=None) -> Trainer:
                 mesh, state.defense_state.layout.d_padded))
     step = make_train_step(loss, opt, byz_mask=byz_mask, defense=defense,
                            attack=attack, spmd_axis_name=spmd_axis_name,
-                           acc_sharding=acc_sharding, jit=False)
+                           acc_sharding=acc_sharding, has_aux=True,
+                           jit=False)
+    if mesh is not None:
+        step = _under_mesh(step, mesh)
     # the new state keeps the placement of the donated one, so its buffers
     # are reused and the next step takes it as it is
     step = jax.jit(step, donate_argnums=0, out_shardings=(shardings, None))

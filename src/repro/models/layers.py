@@ -13,8 +13,8 @@ Implemented temporal-mixing families:
   * Mamba-2 SSD (state-space duality) with chunked training scan and O(1)
     decode state.
 
-Channel mixing: SwiGLU / GeGLU / GELU MLPs and a capacity-based
-expert-parallel MoE (argsort dispatch — no (tokens, E, C) one-hot tensor).
+Channel mixing: SwiGLU / GeGLU / GELU MLPs and a dropless MoE whose
+layer may hold a share of the experts (sorted dispatch, grouped matmuls).
 
 All matmuls accumulate in float32 (``preferred_element_type``) and softmax
 / norms run in float32 regardless of the compute dtype.
@@ -360,7 +360,7 @@ def attn_block_apply(params, cfg, x, *, positions, cache=None,
         q = apply_rope(q, cos, sin, cfg.rope_fraction)
         k = apply_rope(k, cos, sin, cfg.rope_fraction)
 
-    scale = 1.0 / math.sqrt(Dh)
+    scale = cfg.attn_scale or 1.0 / math.sqrt(Dh)
     window = cfg.window if cfg.attn == "sliding" else 0
 
     if cache is None:
@@ -593,87 +593,226 @@ def mlp_init(key, kind: str, d: int, d_ff: int, param_dtype,
 
 
 # ==========================================================================
-# MoE (capacity-based argsort dispatch, expert-parallel friendly)
+# MoE: dropless routed experts, of which a layer may hold a share
 # ==========================================================================
 
+def _ragged_dot(lhs, rhs, group_sizes):
+    """``(N, k) x (G, k, n) -> (N, n)``: row block ``g`` of ``lhs`` (the
+    ``group_sizes[g]`` rows after the blocks before it) times ``rhs[g]``;
+    rows past the last block are not computed and read 0."""
+    return jax.lax.ragged_dot(lhs, rhs, group_sizes,
+                              preferred_element_type=f32)
+
+
+_RAGGED_CONTRACT = jax.lax.RaggedDotDimensionNumbers(
+    dot_dimension_numbers=(([0], [0]), ([], [])),
+    lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
+
+
+def _ragged_dot_t(lhs, rhs, group_sizes):
+    """``(N, k)^T x (N, n) -> (G, k, n)``: per row block ``g``, the block
+    of ``lhs`` transposed times the block of ``rhs``."""
+    return jax.lax.ragged_dot_general(lhs, rhs, group_sizes,
+                                      _RAGGED_CONTRACT,
+                                      preferred_element_type=f32)
+
+
+def _lanes_in_turn(f):
+    """``f`` under ``vmap`` runs each lane in turn: the TPU's ragged dot
+    takes no batch dimension.  Where the context mesh (which
+    ``launch.train.build_trainer`` sets around its step) splits the lanes
+    over its worker axes, as the trainer's worker ``vmap`` is split, each
+    shard runs its own lanes: a loop over the whole axis would have every
+    shard gather and compute every worker's."""
+    f = jax.custom_batching.custom_vmap(f)
+
+    @f.def_vmap
+    def rule(axis_size, in_batched, *args):
+        def lanes(*args):
+            def one(mapped):
+                it = iter(mapped)
+                return f(*[next(it) if b else a
+                           for a, b in zip(args, in_batched)])
+            return jax.lax.map(one, [a for a, b in zip(args, in_batched)
+                                     if b])
+
+        mesh = jax.sharding.get_abstract_mesh()
+        axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
+        n = math.prod(mesh.shape[a] for a in axes)
+        if n > 1 and axis_size % n == 0:
+            lanes = jax.shard_map(
+                lanes, mesh=mesh, out_specs=_P(axes),
+                in_specs=tuple(_P(axes) if b else _P() for b in in_batched))
+        return lanes(*args), True
+
+    return f
+
+
+_gmm = _lanes_in_turn(_ragged_dot)
+_gmm_t = _lanes_in_turn(_ragged_dot_t)
+
+
+@jax.custom_vjp
+def grouped_matmul(lhs, rhs, group_sizes):
+    """The experts' grouped matmul: rows sorted by expert, ``rhs`` the
+    stacked expert weights, f32 out.  Its backward is two more grouped
+    matmuls (the rows' cotangent, the weights' per expert)."""
+    return _gmm(lhs, rhs, group_sizes)
+
+
+def _grouped_matmul_fwd(lhs, rhs, group_sizes):
+    return _gmm(lhs, rhs, group_sizes), (lhs, rhs, group_sizes)
+
+
+def _grouped_matmul_bwd(res, g):
+    lhs, rhs, group_sizes = res
+    g = g.astype(lhs.dtype)
+    d_lhs = _gmm(g, jnp.swapaxes(rhs, 1, 2), group_sizes)
+    d_rhs = _gmm_t(lhs, g, group_sizes)
+    return d_lhs.astype(lhs.dtype), d_rhs.astype(rhs.dtype), None
+
+
+grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
+
+
+def _take_slots(rows, slot):
+    """``rows[slot]`` for ``slot < len(rows)``, else 0: ``(T, K, d)``."""
+    n = rows.shape[0]
+    got = rows[jnp.minimum(slot, n - 1)]
+    return jnp.where((slot < n)[..., None], got, jnp.zeros((), rows.dtype))
+
+
+# Dispatch and combine move rows by a permutation (token assignment <->
+# expert-sorted row), so each direction of each is a gather: the backward
+# passes gather through the inverse map instead of scattering.
+
+@jax.custom_vjp
+def _dispatch(x, assign, slot):
+    """The rows of the expert buffer: row ``r`` is the token of assignment
+    ``assign[r]`` (``x``: (T, d), ``K`` assignments per token).  ``slot``
+    (T, K) is the inverse map: the row of each assignment, or the row
+    count where it has none."""
+    return x[assign // slot.shape[1]]
+
+
+def _dispatch_fwd(x, assign, slot):
+    return _dispatch(x, assign, slot), (assign, slot)
+
+
+def _dispatch_bwd(res, g):
+    _, slot = res
+    return _take_slots(g, slot).astype(f32).sum(1).astype(g.dtype), None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _combine(rows, weights, assign, slot):
+    """Each token's weighted sum of its assignments' rows, f32 (T, d)."""
+    return (_take_slots(rows, slot).astype(f32) * weights[..., None]).sum(1)
+
+
+def _combine_fwd(rows, weights, assign, slot):
+    return _combine(rows, weights, assign, slot), (rows, weights, assign,
+                                                   slot)
+
+
+def _combine_bwd(res, g):
+    rows, weights, assign, slot = res
+    K = slot.shape[1]
+    d_weights = (_take_slots(rows, slot).astype(f32) * g[:, None]).sum(-1)
+    # a row whose assignment points back at it is routed; the rest of the
+    # buffer holds other experts' assignments and gets nothing
+    owned = slot.reshape(-1)[assign] == jnp.arange(rows.shape[0])
+    w_row = jnp.where(owned, weights.reshape(-1)[assign], 0.0)
+    d_rows = (g[assign // K] * w_row[:, None]).astype(rows.dtype)
+    return d_rows, d_weights, None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
 def moe_apply(params, cfg, x):
-    """Top-k routed experts + optional shared experts.
+    """Routed experts (top-k of the router's logits, softmax over the k
+    chosen) + optional shared experts, with no capacity: every assignment
+    to a held expert is computed.
 
-    Dispatch: flatten (token, k) assignments, stable-argsort by expert id,
-    compute each assignment's rank within its expert via searchsorted
-    (no (T, E, C) one-hot), drop beyond capacity, scatter into an
-    (E * C, d) buffer, run the batched expert einsum, gather back weighted.
+    The router scores all ``cfg.n_experts``; the layer holds the weights
+    of ``cfg.held_experts`` and computes exactly the assignments routed to
+    them: assignments sorted by held expert (the rest after them), the
+    tokens gathered into that order, three grouped matmuls over the rows
+    and the weighted sum back per token.  What the experts it does not
+    hold would add is left out.  The buffer has ``T * min(K, G)`` rows,
+    enough for any routing of ``T`` tokens to ``G`` held experts.
 
-    Returns (y, aux_loss) — aux is the switch-style load-balance loss.
+    Returns ``(y, aux)``: ``aux["moe_aux"]`` is the switch-style
+    load-balance loss over all ``n_experts``; ``expert_rows_max`` and
+    ``expert_rows_sum`` are the largest and the total number of rows the
+    held experts computed.
     """
     B, L, d = x.shape
     E, K = cfg.n_experts, cfg.top_k
+    held = cfg.held_experts
+    G = len(held)
     T = B * L
-    cap = max(1, int(cfg.capacity_factor * T * K / E))
-
+    N = T * min(K, G)
     xt = x.reshape(T, d)
-    logits = _einsum("td,de->te", xt, params["router"], dtype=f32)
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_e = jax.lax.top_k(probs, K)                 # (T, K)
-    top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
 
-    # load-balance aux (Switch): E * sum_e f_e * P_e
-    me = probs.mean(axis=0)                                # (E,)
-    ce = jnp.zeros((E,), f32).at[top_e.reshape(-1)].add(1.0) / (T * K)
-    aux = E * jnp.sum(me * ce)
+    with jax.named_scope("moe"):
+        with jax.named_scope("router"):
+            logits = _einsum("td,de->te", xt, params["router"], dtype=f32)
+            top_l, top_e = jax.lax.top_k(logits, K)            # (T, K)
+            weights = jax.nn.softmax(top_l, axis=-1)
+            # load-balance aux (Switch): E * sum_e f_e * P_e
+            me = jax.nn.softmax(logits, axis=-1).mean(axis=0)  # (E,)
+            ce = jnp.zeros((E,), f32).at[top_e.reshape(-1)].add(1.0) / (T * K)
+            aux = E * jnp.sum(me * ce)
 
-    # --- dispatch ----------------------------------------------------------
-    flat_e = top_e.reshape(-1)                             # (T*K,)
-    order = jnp.argsort(flat_e, stable=True)
-    sorted_e = flat_e[order]
-    # rank of each assignment within its expert group
-    first = jnp.searchsorted(sorted_e, sorted_e, side="left")
-    rank = jnp.arange(T * K) - first
-    keep = rank < cap
-    slot = jnp.where(keep, sorted_e * cap + rank, E * cap)  # overflow bin
-    token = order // K
+        with jax.named_scope("dispatch"):
+            local = top_e.reshape(-1) - held.start             # (T*K,)
+            key = jnp.where((local >= 0) & (local < G), local, G)
+            order = jnp.argsort(key, stable=True)
+            group_sizes = (key[:, None] == jnp.arange(G)).sum(
+                0, dtype=jnp.int32)
+            rank = jnp.zeros((T * K,), jnp.int32).at[order].set(
+                jnp.arange(T * K, dtype=jnp.int32), unique_indices=True)
+            slot = jnp.where(key < G, rank, N).reshape(T, K)
+            assign = order[:N]
+            rows = _dispatch(xt, assign, slot)                 # (N, d)
 
-    buf = jnp.zeros((E * cap + 1, d), x.dtype)
-    buf = buf.at[slot].set(xt[token], mode="drop")
-    hidden = buf[:E * cap].reshape(E, cap, d)
-    hidden = constrain(hidden, _mdl(E), _U, None)
+        with jax.named_scope("experts"):
+            gate = jax.nn.silu(grouped_matmul(rows, params["w_gate"],
+                                              group_sizes))
+            up = grouped_matmul(rows, params["w_up"], group_sizes)
+            out = grouped_matmul((gate * up).astype(x.dtype),
+                                 params["w_down"], group_sizes)
 
-    # --- expert compute (batched einsum; shards over E = model axis) ------
-    gate = jax.nn.silu(constrain(
-        jnp.einsum("ecd,edf->ecf", hidden, params["w_gate"],
-                   preferred_element_type=f32), _mdl(E), _U, _U))
-    up = constrain(jnp.einsum("ecd,edf->ecf", hidden, params["w_up"],
-                              preferred_element_type=f32), _mdl(E), _U, _U)
-    out = jnp.einsum("ecf,efd->ecd", (gate * up).astype(x.dtype),
-                     params["w_down"], preferred_element_type=f32
-                     ).astype(x.dtype)
-    out = constrain(out, _mdl(E), _U, None)
-
-    # --- combine -----------------------------------------------------------
-    out_flat = out.reshape(E * cap, d)
-    gathered = jnp.where(keep[:, None],
-                         out_flat[jnp.minimum(slot, E * cap - 1)],
-                         jnp.zeros((1, d), x.dtype))       # (T*K, d)
-    weights = top_p.reshape(-1)[order]
-    y = jnp.zeros((T, d), f32).at[token].add(
-        gathered.astype(f32) * weights[:, None])
+        with jax.named_scope("combine"):
+            y = _combine(out.astype(x.dtype), weights, assign, slot)
 
     if cfg.n_shared_experts > 0:
         y = y + mlp_apply(params["shared"], "swiglu", x).reshape(T, d)
 
-    return y.reshape(B, L, d).astype(x.dtype), aux
+    stats = {"moe_aux": aux,
+             "expert_rows_max": group_sizes.max().astype(f32),
+             "expert_rows_sum": group_sizes.sum().astype(f32)}
+    return y.reshape(B, L, d).astype(x.dtype), stats
 
 
 def moe_init(key, cfg, init_scale=0.02):
+    """The router over all ``n_experts`` (f32) and the held experts'
+    stacked SwiGLU weights, ``(G, d, f)`` and ``(G, f, d)``."""
     d, E, ff = cfg.d_model, cfg.n_experts, cfg.d_expert
+    G = len(cfg.held_experts)
     ks = jax.random.split(key, 5)
     pd = cfg.param_dtype
     mk = lambda k, shape: (init_scale * jax.random.normal(k, shape)).astype(pd)
     p = {
         "router": mk(ks[0], (d, E)).astype(f32),   # router in f32
-        "w_gate": mk(ks[1], (E, d, ff)),
-        "w_up": mk(ks[2], (E, d, ff)),
-        "w_down": mk(ks[3], (E, ff, d)),
+        "w_gate": mk(ks[1], (G, d, ff)),
+        "w_up": mk(ks[2], (G, d, ff)),
+        "w_down": mk(ks[3], (G, ff, d)),
     }
     if cfg.n_shared_experts > 0:
         p["shared"] = mlp_init(ks[4], "swiglu", d,

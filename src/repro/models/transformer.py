@@ -168,9 +168,32 @@ def init_cache(cfg: ModelConfig, batch: int, max_seq: int):
 # Blocks
 # ==========================================================================
 
+def _no_aux():
+    """A layer's MoE statistics where it has no routed experts."""
+    z = jnp.zeros((), f32)
+    return {"moe_aux": z, "expert_rows_max": z, "expert_rows_sum": z}
+
+
+def _add_aux(a, b):
+    """Statistics of two layers: losses and row counts add, the largest
+    row count is the larger."""
+    return {"moe_aux": a["moe_aux"] + b["moe_aux"],
+            "expert_rows_max": jnp.maximum(a["expert_rows_max"],
+                                           b["expert_rows_max"]),
+            "expert_rows_sum": a["expert_rows_sum"] + b["expert_rows_sum"]}
+
+
+def _residual(cfg, x, out):
+    """``x + out``, the branch scaled by ``cfg.residual_multiplier``."""
+    if cfg.residual_multiplier != 1.0:
+        out = out * jnp.asarray(cfg.residual_multiplier, out.dtype)
+    return x + out
+
+
 def _apply_layer(p, cfg, kind, x, positions, cache, cache_pos,
                  max_seq: int = 0):
-    """Pre-norm residual layer.  Returns (x, new_cache, aux).
+    """Pre-norm residual layer.  Returns (x, new_cache, aux), ``aux`` the
+    MoE statistics of :func:`layers.moe_apply` (zeros without experts).
 
     ``cache`` is the decode-time state (None during train/prefill);
     ``max_seq > 0`` marks prefill: attention layers then emit ring-packed
@@ -180,22 +203,22 @@ def _apply_layer(p, cfg, kind, x, positions, cache, cache_pos,
     """
     # anchor the residual stream: replicated over the model axis
     x = L.constrain(x, L._U, L._U, None)
-    aux = jnp.zeros((), f32)
+    aux = _no_aux()
     if kind == "ssm":
         h = L.apply_norm(p["ln"], x, cfg.norm)
         with jax.named_scope("mixer"):
             out, new_cache = L.mamba2_block_apply(p["mixer"], cfg, h,
                                                   cache=cache)
-        return x + out, new_cache, aux
+        return _residual(cfg, x, out), new_cache, aux
     if kind == "rec":
         h = L.apply_norm(p["ln1"], x, cfg.norm)
         with jax.named_scope("mixer"):
             out, new_cache = L.rglru_block_apply(p["rec"], cfg, h,
                                                  cache=cache)
-        x = x + out
+        x = _residual(cfg, x, out)
         h = L.apply_norm(p["ln2"], x, cfg.norm)
         with jax.named_scope("mlp"):
-            x = x + L.mlp_apply(p["mlp"], cfg.mlp, h)
+            x = _residual(cfg, x, L.mlp_apply(p["mlp"], cfg.mlp, h))
         return x, new_cache, aux
 
     h = L.apply_norm(p["ln1"], x, cfg.norm)
@@ -208,14 +231,14 @@ def _apply_layer(p, cfg, kind, x, positions, cache, cache_pos,
             out, new_cache = L.attn_block_apply(
                 p["attn"], cfg, h, positions=positions, cache=cache,
                 cache_pos=cache_pos, max_seq=max_seq)
-    x = x + out
+    x = _residual(cfg, x, out)
     h = L.apply_norm(p["ln2"], x, cfg.norm)
     with jax.named_scope("mlp"):
         if "moe" in p:
             out, aux = L.moe_apply(p["moe"], cfg, h)
         else:
             out = L.mlp_apply(p["mlp"], cfg.mlp, h)
-    return x + out, new_cache, aux
+    return _residual(cfg, x, out), new_cache, aux
 
 
 # ==========================================================================
@@ -244,7 +267,11 @@ def forward(params, cfg: ModelConfig, inputs, *, positions=None,
         for recurrent layers);
       * "decode"  — L == 1, ``cache`` required, returns the updated cache.
 
-    Returns (logits (B, L, V), new_cache_or_None, aux_dict).
+    Returns (logits (B, L, V), new_cache_or_None, aux_dict): ``moe_aux``
+    the layers' summed load-balance loss and, with routed experts,
+    ``expert_rows_max`` / ``expert_rows_mean``, the largest and the mean
+    number of assignments a held expert computed, over layers and held
+    experts.
     """
     if mode == "decode" and cache is None:
         raise ValueError("decode needs a cache")
@@ -261,6 +288,8 @@ def forward(params, cfg: ModelConfig, inputs, *, positions=None,
         else:
             B, Lq = inputs.shape
             x = params["embed"][inputs].astype(cfg.dtype)
+            if cfg.embed_multiplier != 1.0:
+                x = x * jnp.asarray(cfg.embed_multiplier, x.dtype)
 
         cache_pos = (cache["pos"] if cache is not None
                      else jnp.zeros((), jnp.int32))
@@ -272,7 +301,7 @@ def forward(params, cfg: ModelConfig, inputs, *, positions=None,
                 cfg.d_model)
             x = x + pos_emb.astype(cfg.dtype)
 
-    aux_total = jnp.zeros((), f32)
+    aux_total = _no_aux()
     new_cache: Optional[Dict[str, Any]] = {} if want_cache else None
     # activation checkpointing: in train mode, each scanned layer saves
     # only its (bf16) input and recomputes internals in the backward pass —
@@ -293,7 +322,7 @@ def forward(params, cfg: ModelConfig, inputs, *, positions=None,
                               c["rec2"] if c is not None else None)
             xc, nc3, a3 = run(p["attn"], "attn", xc,
                               c["attn"] if c is not None else None)
-            return ((xc, aux + a1 + a2 + a3),
+            return ((xc, _add_aux(_add_aux(_add_aux(aux, a1), a2), a3)),
                     {"rec1": nc1, "rec2": nc2, "attn": nc3})
 
         if cache is not None:
@@ -309,7 +338,7 @@ def forward(params, cfg: ModelConfig, inputs, *, positions=None,
         for t, tp in enumerate(params["tail_blocks"]):
             tc = cache["tail_blocks"][t] if cache is not None else None
             x, ntc, a = run(tp, "rec", x, tc)
-            aux_total = aux_total + a
+            aux_total = _add_aux(aux_total, a)
             new_tail.append(ntc)
         if want_cache:
             new_cache["super_blocks"] = new_super
@@ -321,14 +350,14 @@ def forward(params, cfg: ModelConfig, inputs, *, positions=None,
         for i in range(n_pre):
             pc = cache["pre_blocks"][i] if cache is not None else None
             x, npc, a = run(params["pre_blocks"][i], kinds[i], x, pc)
-            aux_total = aux_total + a
+            aux_total = _add_aux(aux_total, a)
             new_pre.append(npc)
         kind = kinds[n_pre] if cfg.n_layers > n_pre else "attn"
 
         def block_body(carry, p, c):
             xc, aux = carry
             xc, nc, a = run(p, kind, xc, c)
-            return (xc, aux + a), nc
+            return (xc, _add_aux(aux, a)), nc
 
         if cache is not None:
             fn = lambda carry, xs: block_body(carry, xs[0], xs[1])
@@ -349,11 +378,18 @@ def forward(params, cfg: ModelConfig, inputs, *, positions=None,
                 else params["lm_head"])
         logits = jnp.einsum("bld,dv->blv", x, head.astype(x.dtype),
                             preferred_element_type=f32)
+        if cfg.logits_divisor != 1.0:
+            logits = logits / cfg.logits_divisor
         logits = L.constrain(logits, L._U, L._U, L._mdl(cfg.vocab_size))
 
     if want_cache:
         new_cache["pos"] = cache_pos + Lq
-    aux = {"moe_aux": aux_total}
+    aux = {"moe_aux": aux_total["moe_aux"]}
+    if cfg.n_experts > 0:
+        n_moe = cfg.n_layers - cfg.first_k_dense
+        aux["expert_rows_max"] = aux_total["expert_rows_max"]
+        aux["expert_rows_mean"] = aux_total["expert_rows_sum"] / (
+            n_moe * len(cfg.held_experts))
     return logits, new_cache, aux
 
 
@@ -382,8 +418,10 @@ def cross_entropy(logits, targets, mask=None):
     return (nll * maskf).sum() / jnp.maximum(maskf.sum(), 1.0)
 
 
-def loss_fn(params, cfg: ModelConfig, batch, *, positions=None):
-    """Next-token LM loss.  batch: {"tokens": (B, L)} or, for stub
+def loss_and_load(params, cfg: ModelConfig, batch, *, positions=None):
+    """Next-token LM loss and the held routed experts' load: the row
+    counts of :func:`forward` (``expert_rows_max``, ``expert_rows_mean``),
+    ``{}`` without routed experts.  batch: {"tokens": (B, L)} or, for stub
     frontends, {"embeds": (B, L, d), "labels": (B, L)}."""
     if cfg.embed_stub:
         inputs, labels = batch["embeds"], batch["labels"]
@@ -395,7 +433,12 @@ def loss_fn(params, cfg: ModelConfig, batch, *, positions=None):
         loss = cross_entropy(logits[:, :-1], labels[:, 1:])
     if cfg.n_experts > 0:
         loss = loss + cfg.router_aux_coef * aux["moe_aux"] / cfg.n_layers
-    return loss
+    return loss, {k: v for k, v in aux.items() if k != "moe_aux"}
+
+
+def loss_fn(params, cfg: ModelConfig, batch, *, positions=None):
+    """Next-token LM loss (:func:`loss_and_load` without the load)."""
+    return loss_and_load(params, cfg, batch, positions=positions)[0]
 
 
 def prefill(params, cfg: ModelConfig, inputs, *, max_seq: int,

@@ -85,7 +85,7 @@ class MetricSpec:
     name: str
     dtype: str                      # canonical: float32 | int32 | bool
     shape_class: str                # one of SHAPE_CLASSES
-    source: str                     # trainer | defense | probe | attack
+    source: str                     # trainer | defense | probe | attack | model
     description: str = ""
     window: Optional[str] = None    # "B" | "A" for guard-window stats
     agg: Optional[str] = None       # tap surface: mean | last | host
@@ -182,6 +182,12 @@ METRICS: Dict[str, MetricSpec] = _spec_table([
                "live eviction threshold, outer (T1) guard", window="A"),
     MetricSpec("grad_norm", "float32", SCALAR, "trainer",
                "norm of the aggregated (post-defense) direction"),
+    MetricSpec("expert_rows_max", "float32", PER_WORKER, "model",
+               "largest number of assignments a held routed expert "
+               "computed in this step's forward, over layers"),
+    MetricSpec("expert_rows_mean", "float32", PER_WORKER, "model",
+               "mean number of assignments per held routed expert and "
+               "layer in this step's forward"),
     MetricSpec("escape_on", "float32", SCALAR, "trainer",
                "sgd_escape perturbation gate (1 = noise injected)"),
     MetricSpec("attack_level", "float32", SCALAR, "attack",

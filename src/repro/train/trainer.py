@@ -142,6 +142,7 @@ def make_train_step(loss_fn: Callable, opt: OptimizerBundle, *,
                     perturb: str = "none", escape_nu=0.0,
                     escape_thresh=0.1,
                     so_probe: Optional[Callable] = None,
+                    has_aux: bool = False,
                     jit: bool = True):
     """Build the jitted training step.
 
@@ -174,6 +175,10 @@ def make_train_step(loss_fn: Callable, opt: OptimizerBundle, *,
     traced into the metrics every step — the second-order trace lane of
     the planted-saddle testbed (``data.saddle.make_probe``: the analytic
     ``true_grad_norm`` / ``min_eig_proxy`` / ``escaped``).
+
+    ``has_aux``: ``loss_fn`` returns ``(loss, {name: scalar})``; each
+    name becomes a per-worker ``(m,)`` metric (the held experts' row
+    counts of ``models.transformer.loss_and_load``).
     """
     defense = resolve_defense(defense, sg_cfg, aggregator)
     if acc_sharding is None:
@@ -183,6 +188,7 @@ def make_train_step(loss_fn: Callable, opt: OptimizerBundle, *,
         raise ValueError(f"unknown perturbation mode {perturb!r} "
                          "(one of 'none', 'sgd_escape')")
     m = int(byz_mask.shape[0])
+    scalar_loss = (lambda p, b: loss_fn(p, b)[0]) if has_aux else loss_fn
 
     def step_fn(state: TrainState, batch, held_batch=None):
         if perturb == "sgd_escape":
@@ -195,9 +201,10 @@ def make_train_step(loss_fn: Callable, opt: OptimizerBundle, *,
         # compiled step's op_name metadata: the device trace's per-phase
         # times are read by these names
         with jax.named_scope("fwd_bwd"):
-            vg = jax.value_and_grad(loss_fn)
-            losses, grads = jax.vmap(lambda wb: vg(state.params, wb),
-                                     spmd_axis_name=spmd_axis_name)(batch)
+            vg = jax.value_and_grad(loss_fn, has_aux=has_aux)
+            out, grads = jax.vmap(lambda wb: vg(state.params, wb),
+                                  spmd_axis_name=spmd_axis_name)(batch)
+            losses, loss_aux = out if has_aux else (out, {})
 
         # (2) Byzantine simulation — the attack state already absorbed the
         # previous step's public defense feedback (observe, below)
@@ -212,13 +219,14 @@ def make_train_step(loss_fn: Callable, opt: OptimizerBundle, *,
                 "loss": losses.mean(),
                 "honest_loss": (losses * (~byz_mask)).sum()
                 / jnp.maximum((~byz_mask).sum(), 1),
+                **loss_aux,
             }
         ctx = {"rng": k_noise, "acc_sharding": acc_sharding}
         with jax.named_scope("defense"):
             if defense.needs_held_batch:
                 if held_batch is None:
                     raise ValueError(f"{defense.name} needs a held-out batch")
-                ctx["scores"] = zeno_scores(loss_fn, state.params, grads,
+                ctx["scores"] = zeno_scores(scalar_loss, state.params, grads,
                                             held_batch, eta=zeno_eta,
                                             rho=zeno_rho)
             agg, defense_state, info = defense.aggregate(state.defense_state,

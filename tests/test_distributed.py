@@ -77,6 +77,66 @@ def test_sharded_safeguard_matches_local():
     assert "DISTRIBUTED_OK" in out.stdout, (out.stdout, out.stderr)
 
 
+_MOE_SCRIPT = r"""
+import os, re, sys
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import jax, numpy as np
+import repro.configs as C
+from repro.launch import train as train_lib
+
+data_n, model_n, m = (int(a) for a in sys.argv[1:4])
+cfg = C.get_smoke("granite-moe-3b-a800m")
+args = train_lib.parse_args([
+    "--arch", "granite-moe-3b-a800m", "--workers", str(m), "--byz", "1",
+    "--batch", str(2 * m), "--seq", "32", "--attack", "sign_flip",
+    "--defense", "safeguard_double", "--t0", "5", "--t1", "10",
+    "--floor", "0.01", "--lr", "0.05"])
+
+def run(mesh):
+    tr = train_lib.build_trainer(cfg, args, mesh=mesh)
+    loss, good = [], []
+    for _ in range(12):
+        tr.state, met = tr.step_fn(tr.state, next(tr.data_iter))
+        loss.append(np.asarray(met["loss"]))
+        good.append(np.asarray(met["good"]))
+    return tr, np.array(loss), np.array(good)
+
+_, loss_ref, good_ref = run(None)
+mesh = jax.make_mesh((data_n, model_n), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
+tr, loss, good = run(mesh)
+assert (good == good_ref).all(), (good, good_ref)
+assert not good[-1][0] and good[:, 1:].all()
+np.testing.assert_allclose(loss, loss_ref, rtol=1e-5)
+
+# the grouped matmuls' per-worker loops, per chip: over the chip's own
+# m / data workers, not all m
+txt = tr.step_fn.lower(tr.state, next(tr.data_iter)).compile().as_text()
+trips = [int(n) for n in re.findall(
+    r'op_name="[^"]*/moe/experts/[^"]*while"[^\n]*'
+    r'known_trip_count":\{"n":"(\d+)"', txt)]
+assert trips and set(trips) == {m // data_n}, trips
+print("MOE_MESH_OK")
+"""
+
+
+@pytest.mark.parametrize("data_n,model_n,m", [(4, 1, 8), (2, 2, 4)])
+def test_held_expert_step_on_a_mesh_matches_one_device(data_n, model_n, m):
+    """Granite-MoE's smoke step through ``build_trainer`` on a
+    ``(data, model)`` mesh of 4 host devices against one device: the same
+    losses and the same evictions in 12 steps (the sign-flipper evicted,
+    no honest worker), and each chip runs the grouped matmuls of its own
+    two workers only."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = "src"
+    out = subprocess.run([sys.executable, "-c", _MOE_SCRIPT, str(data_n),
+                          str(model_n), str(m)], env=env,
+                         capture_output=True, text=True, timeout=600,
+                         cwd=os.path.dirname(os.path.dirname(
+                             os.path.abspath(__file__))))
+    assert "MOE_MESH_OK" in out.stdout, (out.stdout, out.stderr[-4000:])
+
+
 @pytest.mark.slow
 def test_dryrun_single_pair_end_to_end():
     """Full dry-run driver on the smallest pair (its own process — it

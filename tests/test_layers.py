@@ -231,16 +231,107 @@ def test_flash_jnp_grad_matches_dense(rng):
                                atol=1e-4)
 
 
+def _moe_cfg(arch="granite-moe-3b-a800m", **kw):
+    import dataclasses
+    import repro.configs as C
+    return dataclasses.replace(C.get_smoke(arch), **kw)
+
+
+def _moe_dense(params, cfg, x):
+    """Every expert on every token times the token's routing weight for it
+    (0 outside its top k), plus the shared experts: no sort, no dispatch."""
+    xt = x.reshape(-1, cfg.d_model)
+    top_l, top_e = jax.lax.top_k(xt @ params["router"], cfg.top_k)
+    gates = jax.nn.softmax(top_l, axis=-1)
+    y = 0.0
+    for j, e in enumerate(cfg.held_experts):
+        h = (jax.nn.silu(xt @ params["w_gate"][j]) * (xt @ params["w_up"][j]))
+        y = y + ((gates * (top_e == e)).sum(-1)[:, None]
+                 * (h @ params["w_down"][j]))
+    if cfg.n_shared_experts:
+        y = y + L.mlp_apply(params["shared"], "swiglu", x).reshape(xt.shape)
+    return y.reshape(x.shape)
+
+
 def test_moe_aux_loss_uniform_router():
     """A perfectly uniform router gives aux loss ~= 1 (Switch norm)."""
-    import repro.configs as C
-    import dataclasses
-    cfg = dataclasses.replace(C.get_smoke("granite-moe-3b-a800m"),
-                              capacity_factor=8.0)
+    cfg = _moe_cfg()
     key = jax.random.PRNGKey(0)
     params = L.moe_init(key, cfg)
     params["router"] = jnp.zeros_like(params["router"])
     x = jax.random.normal(key, (2, 16, cfg.d_model))
     y, aux = L.moe_apply(params, cfg, x)
     assert y.shape == x.shape
-    assert abs(float(aux) - 1.0) < 0.05
+    assert abs(float(aux["moe_aux"]) - 1.0) < 0.05
+
+
+@pytest.mark.parametrize("arch", ["granite-moe-3b-a800m", "deepseek-v2-236b"])
+def test_moe_whole_layer_matches_dense_loop(arch, rng):
+    """Values and gradients (router included) of the sorted, grouped layer
+    equal the dense per-expert loop, in f32 (only the order of sums
+    differs)."""
+    cfg = _moe_cfg(arch, n_experts=8, top_k=3)
+    params = L.moe_init(rng, cfg)
+    x = jax.random.normal(jax.random.fold_in(rng, 1), (2, 16, cfg.d_model))
+    y, _ = L.moe_apply(params, cfg, x)
+    np.testing.assert_allclose(np.asarray(y),
+                               np.asarray(_moe_dense(params, cfg, x)),
+                               rtol=1e-5, atol=1e-6)
+    sq = lambda f: lambda p, x: (f(p, x) ** 2).sum()
+    g_layer = jax.grad(sq(lambda p, x: L.moe_apply(p, cfg, x)[0]),
+                       argnums=(0, 1))(params, x)
+    g_dense = jax.grad(sq(lambda p, x: _moe_dense(p, cfg, x)),
+                       argnums=(0, 1))(params, x)
+    for a, b in zip(jax.tree.leaves(g_layer), jax.tree.leaves(g_dense)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=1e-6)
+
+
+@pytest.mark.parametrize("arch", ["granite-moe-3b-a800m", "deepseek-v2-236b"])
+def test_moe_held_shares_sum_to_whole_layer(arch, rng):
+    """Four layers holding disjoint quarters of the experts (router whole)
+    add up to the layer that holds them all, with the shared experts that
+    every share computes counted once."""
+    cfg = _moe_cfg(arch, n_experts=8, top_k=3)
+    params = L.moe_init(rng, cfg)
+    x = jax.random.normal(jax.random.fold_in(rng, 1), (2, 16, cfg.d_model))
+    whole, stats = L.moe_apply(params, cfg, x)
+    total, rows = 0.0, 0.0
+    for s in range(4):
+        share = _moe_cfg(arch, n_experts=8, top_k=3, first_held_expert=2 * s,
+                         n_held_experts=2)
+        p = {**params, **{k: params[k][2 * s:2 * s + 2]
+                          for k in ("w_gate", "w_up", "w_down")}}
+        y, st = L.moe_apply(p, share, x)
+        total, rows = total + y, rows + st["expert_rows_sum"]
+        np.testing.assert_allclose(float(st["moe_aux"]),
+                                   float(stats["moe_aux"]), rtol=1e-6)
+    if cfg.n_shared_experts:
+        total = total - 3 * L.mlp_apply(params["shared"], "swiglu", x)
+    np.testing.assert_allclose(np.asarray(total), np.asarray(whole),
+                               rtol=1e-5, atol=1e-6)
+    assert float(rows) == float(stats["expert_rows_sum"]) == 32 * 3
+
+
+def test_moe_drops_nothing_when_every_token_picks_one_expert(rng):
+    """A router biased to send every token to expert 5: that expert
+    computes all 64 tokens, and the layer still equals the dense loop."""
+    cfg = _moe_cfg(n_experts=8, top_k=2)
+    params = L.moe_init(rng, cfg)
+    params["router"] = params["router"].at[0, 5].set(100.0)
+    x = jax.random.normal(jax.random.fold_in(rng, 1), (4, 16, cfg.d_model))
+    x = x.at[..., 0].set(10.0)
+    y, stats = L.moe_apply(params, cfg, x)
+    assert float(stats["expert_rows_max"]) == 64
+    np.testing.assert_allclose(np.asarray(y),
+                               np.asarray(_moe_dense(params, cfg, x)),
+                               rtol=1e-5, atol=1e-6)
+    # the share that holds expert 5 computes all its tokens, likewise
+    share = _moe_cfg(n_experts=8, top_k=2, first_held_expert=4,
+                     n_held_experts=2)
+    p = {**params, **{k: params[k][4:6] for k in ("w_gate", "w_up", "w_down")}}
+    y, stats = L.moe_apply(p, share, x)
+    assert float(stats["expert_rows_max"]) == 64
+    np.testing.assert_allclose(np.asarray(y),
+                               np.asarray(_moe_dense(p, share, x)),
+                               rtol=1e-5, atol=1e-6)

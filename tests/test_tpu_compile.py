@@ -178,3 +178,38 @@ def test_scoped_smoke_step_names_its_gram_kernel_for_v5e(one_chip,
         parts = op_name.split("/")
         assert "defense" in parts and "accumulate" in parts
         assert parts.index("defense") < parts.index("accumulate")
+
+
+def test_held_expert_layer_compiles_for_v5e_under_worker_vmap(one_chip):
+    """Granite-3.0-3B-A800M's MoE layer at one chip's share (10 of 40
+    experts, published widths), its gradient vmapped over 4 workers of
+    4096 tokens as the train step takes it: the TPU's ragged dot (which
+    takes no batch dimension) compiles, forward and backward, for each
+    worker in turn.  The compiler names each grouped matmul
+    ``ragged-dot-none.N`` (its ``op_name`` too), by which a device trace
+    finds them."""
+    import dataclasses
+    from repro import configs as C
+    from repro.models import layers as L
+    cfg = dataclasses.replace(C.get("granite-moe-3b-a800m"),
+                              n_held_experts=10)
+    put = lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+    params = jax.tree.map(put, jax.eval_shape(
+        lambda: L.moe_init(jax.random.PRNGKey(0), cfg)))
+    x = jax.ShapeDtypeStruct((4, 1, 4096, cfg.d_model), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def grads(p, x):
+        loss = lambda p, xi: L.moe_apply(p, cfg, xi)[0].astype(
+            jnp.float32).sum()
+        return jax.vmap(jax.grad(loss, argnums=(0, 1)), in_axes=(None, 0))(
+            p, x)
+
+    compiled = jax.jit(grads).lower(params, x).compile()
+    dots = re.findall(r'^\s*%?ragged-dot-none[.\d]* = .*custom-call\(',
+                      compiled.as_text(), flags=re.M)
+    # three matmuls forward, and the rows' and the weights' cotangents of
+    # each backward (the compiler may run a forward one twice)
+    assert len(dots) >= 9
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < HBM_BYTES
