@@ -8,8 +8,10 @@
     while streaming each tile exactly once;
   * ``robust_agg``       — coordinate-wise median / trimmed-mean baselines
     (VPU sorting networks over the worker axis, d-tiled);
-  * ``flash_attention``  — causal (+sliding-window, +GQA) blocked
-    online-softmax attention shared by all transformer archs.
+  * ``flash_attention``  — the train/prefill path's causal GQA attention
+    on the TPU (``flash_attention_train``: JAX's splash forward, dq and
+    dkv kernels), and a forward-only causal (+sliding-window, +GQA)
+    blocked online-softmax kernel.
 
 Each package ships ``kernel.py`` (pl.pallas_call + BlockSpec), ``ops.py``
 (jit-able wrapper with padding/tile choice/dispatch) and ``ref.py``

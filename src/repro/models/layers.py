@@ -28,6 +28,9 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.flash_attention import (flash_attention_train,
+                                           train_kernel_fits)
+
 f32 = jnp.float32
 
 # --------------------------------------------------------------------------
@@ -288,6 +291,23 @@ def flash_attention_jnp(q, k, v, *, scale: float, window: int = 0,
 FLASH_THRESHOLD = 1024
 
 
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def _flash_kernel_fits(q, v, *, window: int) -> bool:
+    """Whether the blocked flash path can run as the Pallas kernel
+    (``flash_attention_train``) rather than ``flash_attention_jnp``: on the
+    TPU, causal with no window, equal q/k and v head sizes, the sequence a
+    multiple of the kernel's blocks, heads not split over a ``model`` axis
+    and no context mesh of several devices (a Mosaic kernel is not
+    partitioned by the compiler)."""
+    return (_on_tpu() and window == 0 and q.shape[-1] == v.shape[-1]
+            and train_kernel_fits(q.shape[1])
+            and (not _ACT["on"] or _ACT["model_n"] == 1)
+            and jax.sharding.get_abstract_mesh().size <= 1)
+
+
 def causal_mask(Lq: int, Lk: int, *, q_offset=0, window: int = 0):
     """(Lq, Lk) boolean mask; query i sits at absolute position
     ``q_offset + i``, key j at absolute position j.  ``window`` > 0 further
@@ -364,13 +384,18 @@ def attn_block_apply(params, cfg, x, *, positions, cache=None,
     window = cfg.window if cfg.attn == "sliding" else 0
 
     if cache is None:
-        if L >= FLASH_THRESHOLD:
-            kx = jnp.repeat(k, H // K, axis=2)      # expand GQA -> MHA so
-            vx = jnp.repeat(v, H // K, axis=2)      # the head dim shards
-            kx = constrain(kx, _U, _U, _mdl(H), None)
-            vx = constrain(vx, _U, _U, _mdl(H), None)
-            out = flash_attention_jnp(q, kx, vx, scale=scale, window=window)
-            out = out.reshape(B, L, H * Dh)
+        if L >= FLASH_THRESHOLD and _flash_kernel_fits(q, v, window=window):
+            with jax.named_scope("attn_kernel"):
+                out = flash_attention_train(q, k, v, scale=scale)
+        elif L >= FLASH_THRESHOLD:
+            with jax.named_scope("attn_jnp"):
+                kx = jnp.repeat(k, H // K, axis=2)  # expand GQA -> MHA so
+                vx = jnp.repeat(v, H // K, axis=2)  # the head dim shards
+                kx = constrain(kx, _U, _U, _mdl(H), None)
+                vx = constrain(vx, _U, _U, _mdl(H), None)
+                out = flash_attention_jnp(q, kx, vx, scale=scale,
+                                          window=window)
+                out = out.reshape(B, L, H * Dh)
         else:
             mask = causal_mask(L, L, window=window)[None, None, None]
             out = attention(q, k, v, scale=scale, mask=mask)

@@ -231,6 +231,68 @@ def test_flash_jnp_grad_matches_dense(rng):
                                atol=1e-4)
 
 
+def _attn_paths_taken(monkeypatch, cfg, seq, *, decode=False):
+    """Trace one attention layer (shapes only) with the backend check
+    patched to the TPU; which of the kernel and ``flash_attention_jnp``
+    it calls (the dense paths call neither)."""
+    taken = []
+
+    def kernel(q, k, v, *, scale):
+        taken.append("kernel")
+        return jnp.zeros(q.shape[:2] + (q.shape[2] * q.shape[3],), q.dtype)
+
+    def jnp_path(q, k, v, *, scale, window=0):
+        taken.append("jnp")
+        return jnp.zeros(q.shape, q.dtype)
+
+    monkeypatch.setattr(L, "_on_tpu", lambda: True)
+    monkeypatch.setattr(L, "flash_attention_train", kernel)
+    monkeypatch.setattr(L, "flash_attention_jnp", jnp_path)
+    params = L.attn_block_init(jax.random.PRNGKey(0), cfg)
+    if decode:
+        x = jnp.zeros((1, 1, cfg.d_model), cfg.dtype)
+        cache = L.attn_cache_init(cfg, 1, seq, cfg.dtype)
+        run = lambda p, x: L.attn_block_apply(
+            p, cfg, x, positions=jnp.zeros((1, 1), jnp.int32), cache=cache,
+            cache_pos=jnp.asarray(seq - 1, jnp.int32))
+    else:
+        x = jnp.zeros((1, seq, cfg.d_model), cfg.dtype)
+        run = lambda p, x: L.attn_block_apply(
+            p, cfg, x, positions=jnp.arange(seq)[None])
+    jax.eval_shape(run, params, x)
+    return taken
+
+
+def test_attention_takes_the_kernel_only_where_its_inputs_allow(
+        monkeypatch):
+    """On the TPU a causal GQA layer at ``L >= FLASH_THRESHOLD`` runs the
+    Pallas kernel; a window, heads split over a ``model`` axis and a
+    context mesh of several devices keep ``flash_attention_jnp``; decode
+    and sequences below the threshold stay on dense attention."""
+    import dataclasses
+    import repro.configs as C
+    cfg = C.get_smoke("granite-moe-3b-a800m")
+    n = L.FLASH_THRESHOLD
+    assert _attn_paths_taken(monkeypatch, cfg, n) == ["kernel"]
+    assert _attn_paths_taken(monkeypatch, cfg, 4 * n) == ["kernel"]
+    windowed = dataclasses.replace(cfg, attn="sliding", window=256)
+    assert _attn_paths_taken(monkeypatch, windowed, n) == ["jnp"]
+    assert _attn_paths_taken(monkeypatch, cfg, n // 2) == []
+    assert _attn_paths_taken(monkeypatch, cfg, n, decode=True) == []
+    # a sequence the kernel's blocks do not divide
+    assert _attn_paths_taken(monkeypatch, cfg, n + 64) == ["jnp"]
+    with monkeypatch.context() as mp:
+        mp.setattr(L, "constrain", lambda x, *spec: x)   # no mesh here
+        L.enable_activation_sharding(True, model_n=2)
+        try:
+            assert _attn_paths_taken(mp, cfg, n) == ["jnp"]
+        finally:
+            L.enable_activation_sharding(False)
+    mesh = jax.sharding.AbstractMesh((2,), ("data",))
+    with jax.sharding.use_abstract_mesh(mesh):
+        assert _attn_paths_taken(monkeypatch, cfg, n) == ["jnp"]
+
+
 def _moe_cfg(arch="granite-moe-3b-a800m", **kw):
     import dataclasses
     import repro.configs as C

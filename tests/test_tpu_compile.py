@@ -213,3 +213,51 @@ def test_held_expert_layer_compiles_for_v5e_under_worker_vmap(one_chip):
     assert len(dots) >= 9
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < HBM_BYTES
+
+
+def _granite_attention_grads(one_chip, on_tpu: bool):
+    """One Granite-3.0-3B-A800M attention layer at the cell's widths (24
+    query and 8 KV heads of 64, d 1536), its gradient under the layer's
+    remat and vmapped over 4 workers of 4096 tokens as the train step
+    takes it, compiled for one v5e chip with the backend check patched to
+    ``on_tpu``."""
+    from repro import configs as C
+    from repro.models import layers as L
+    cfg = C.get("granite-moe-3b-a800m")
+    m, seq = 4, 4096
+    put = lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+    params = jax.tree.map(put, jax.eval_shape(
+        lambda: L.attn_block_init(jax.random.PRNGKey(0), cfg)))
+    x = jax.ShapeDtypeStruct((m, 1, seq, cfg.d_model), jnp.bfloat16,
+                             sharding=one_chip)
+    positions = jnp.arange(seq)[None]
+
+    def grads(p, x):
+        layer = jax.checkpoint(lambda p, xi: L.attn_block_apply(
+            p, cfg, xi, positions=positions)[0].astype(jnp.float32).sum())
+        return jax.vmap(jax.grad(layer, argnums=(0, 1)), in_axes=(None, 0))(
+            p, x)
+
+    with mock.patch.object(L, "_on_tpu", lambda: on_tpu):
+        return jax.jit(grads).lower(params, x).compile()
+
+
+def test_granite_attention_runs_the_pallas_kernel_for_v5e(one_chip):
+    """The Granite cell's attention compiles for v5e as splash's Pallas
+    kernels, the forward and the fused backward (one dkv kernel that also
+    forms dq), with no loop of ``flash_attention_jnp`` left, and needs no
+    more temporaries than the jnp path at the same shape."""
+    kernel = _granite_attention_grads(one_chip, on_tpu=True)
+    text = kernel.as_text()
+    calls = lambda name: re.findall(
+        rf"^\s*%?{name}[.\d]* = .*custom-call\(", text, flags=re.M)
+    assert calls("splash_mha_fwd_residuals")
+    assert calls("splash_mha_dkv_no_residuals")
+    assert not calls("splash_mha_dq")
+    assert "attn_kernel" in text
+    assert "attn_jnp" not in text
+    assert not re.search(r"^\s*%?while[.\d]* = ", text, flags=re.M)
+    jnp_path = _granite_attention_grads(one_chip, on_tpu=False)
+    assert "attn_jnp" in jnp_path.as_text()
+    assert (kernel.memory_analysis().temp_size_in_bytes
+            <= jnp_path.memory_analysis().temp_size_in_bytes)
