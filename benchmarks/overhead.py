@@ -12,14 +12,14 @@ asserted:
   safeguard_stacked    paper-faithful stacked-pytree accumulators
                        (4 tree traversals per step: 2 accumulates + 2
                        leaf-wise Grams)
-  safeguard_flat       flat (m, d_pad) buffers: in-place scatter
+  safeguard_flat       flat (m, d_pad) buffers on the pass the step picks
+                       on this platform: off the TPU the in-place scatter
                        accumulate + blocked Pallas Gram kernel
-                       (interpret off-TPU)
-  safeguard_flat_xla   flat buffers: scatter accumulate + one XLA dot
-                       (the sharded at-scale backend)
-  safeguard_flat_fused flat buffers: single streamed accumulate+distance
-                       Pallas kernel over the flattened gradient matrix
-                       (the TPU hot path; pays a flatten on CPU)
+                       (interpreted), on a TPU the per-leaf in-place
+                       accumulate+Gram kernel
+  safeguard_flat_xla   flat buffers pinned to a one-device sharding:
+                       scatter accumulate + one XLA dot (the pass of a
+                       sharded mesh)
   safeguard_sketch     CountSketch O(m r k) state (beyond paper)
 
 Builds ONE record (raw rows + per-d safeguard entries with flat-vs-
@@ -38,6 +38,7 @@ import time
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import SafeguardConfig, init_state, safeguard_step
 from repro.core import aggregators as agg_lib
@@ -75,12 +76,18 @@ def make_grads(params, key):
                   for k, leaf in zip(keys, leaves)])
 
 
+def _one_device_sharding():
+    """The accumulators pinned to one device: the step takes the pass it
+    takes on a sharded mesh."""
+    return NamedSharding(Mesh(jax.devices()[:1], ("data",)), P())
+
+
+# (name, SafeguardConfig fields, pin the accumulators to a sharding)
 SAFEGUARD_VARIANTS = (
-    ("safeguard_stacked", dict(engine="stacked")),
-    ("safeguard_flat", dict(engine="flat", backend="pallas")),
-    ("safeguard_flat_xla", dict(engine="flat", backend="xla")),
-    ("safeguard_flat_fused", dict(engine="flat", backend="pallas_fused")),
-    ("safeguard_sketch", dict(use_sketch=True, sketch_k=1024)),
+    ("safeguard_stacked", dict(engine="stacked"), False),
+    ("safeguard_flat", dict(engine="flat"), False),
+    ("safeguard_flat_xla", dict(engine="flat"), True),
+    ("safeguard_sketch", dict(use_sketch=True, sketch_k=1024), False),
 )
 
 
@@ -101,11 +108,13 @@ def run(out_dir: str = "experiments/bench", quick: bool = False,
             rows.append({"defense": name, "d": d, "us_per_call": us})
             print(f"overhead,{name},d={d},{us:.1f}us")
 
-        for variant, kw in SAFEGUARD_VARIANTS:
+        for variant, kw, pinned in SAFEGUARD_VARIANTS:
             cfg = SafeguardConfig(m=M, T0=50, T1=200, threshold_floor=1.0,
                                   **kw)
             st = init_state(cfg, params)
-            fn = jax.jit(lambda s, g: safeguard_step(s, g, cfg))
+            shd = _one_device_sharding() if pinned else None
+            fn = jax.jit(lambda s, g: safeguard_step(s, g, cfg,
+                                                     acc_sharding=shd))
             us = _time(fn, st, grads, iters=iters)
             rows.append({"defense": variant, "d": d, "us_per_call": us})
             print(f"overhead,{variant},d={d},{us:.1f}us")
@@ -130,7 +139,7 @@ def _build_record(rows):
               "rows": rows, "entries": []}
     for d in ds:
         entry = {"d": d}
-        for variant, _ in SAFEGUARD_VARIANTS:
+        for variant, _, _ in SAFEGUARD_VARIANTS:
             if (variant, d) in by:
                 entry[variant] = round(by[(variant, d)], 1)
         stacked = by.get(("safeguard_stacked", d))

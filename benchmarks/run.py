@@ -16,7 +16,6 @@ measurement (``name,...``) and writes JSON artifacts under
                   trace_zeta=False (BENCH_trace_overhead.json)
   live_overhead   live-telemetry cost: scan_trial tap_every=50/10 vs
                   untapped (BENCH_live_overhead.json)
-  kernels         Pallas kernels (interpret) vs jnp reference
   roofline        three-term roofline per (arch x shape) from the dry runs
 """
 
@@ -41,7 +40,7 @@ def main() -> None:
 
     from benchmarks import (table1_attack_grid, fig2_detection, fig2_reset,
                             convex_attack, saddle_escape, overhead,
-                            campaign_throughput, bench_kernels, roofline,
+                            campaign_throughput, roofline,
                             trace_overhead, live_overhead)
     jobs = {
         "table1": lambda: table1_attack_grid.run(steps=steps),
@@ -57,7 +56,6 @@ def main() -> None:
             steps=60 if args.quick else 150),
         "live_overhead": lambda: live_overhead.run(
             steps=100 if args.quick else 150),
-        "kernels": bench_kernels.run,
         "roofline": roofline.run,
     }
     selected = (args.only.split(",") if args.only else list(jobs))

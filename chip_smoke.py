@@ -7,9 +7,9 @@ The run is built through ``repro.launch.train.build_trainer`` — the same
 config -> defense -> ``make_train_step`` -> ``Trainer`` wiring as the
 training CLI, with the state donated to the jitted step.  One chip:
 ``tinyllama-1.1b`` at its published widths cut to 2 layers, m = 4 workers
-(1 Byzantine, ``sign_flip``), the double safeguard on the one-device
-backend ``build_trainer`` picks (``pallas_fused``: one in-place
-accumulate+Gram kernel call per gradient leaf).  It fails unless the
+(1 Byzantine, ``sign_flip``), the double safeguard on the path it takes
+on one TPU chip with f32 accumulators (one in-place accumulate+Gram
+kernel call per gradient leaf).  It fails unless the
 compiled step contains a Pallas kernel (``tpu_custom_call``), every logged
 loss is finite, the Pallas Gram kernel's distances of the final
 short-window buffer match ``kernels/safeguard_filter/ref.py``, and by the

@@ -24,27 +24,28 @@ Three state representations are provided (DESIGN.md §6):
   * **flat** (default): the accumulators are single ``(m, d_pad)``
     matrices in one fixed ``tree_flatten`` layout (:class:`FlatLayout`,
     computed once at :func:`init_state`; :func:`unflatten_row` recovers a
-    parameter pytree for diagnostics).  Three backends:
+    parameter pytree for diagnostics).  The step picks one of three
+    distance passes from what it observes (:func:`safeguard_step`):
 
-    - ``backend="pallas_fused"`` (the single-device TPU path that
-      ``launch.train.build_trainer`` takes): one streamed, in-place pass
-      per gradient leaf.  A Pallas kernel reads the leaf in its own dtype,
+    - an ``acc_sharding`` (a mesh): in-place column-slice adds, then the
+      oracle's fused f32 multiply-reduce, the one pass XLA can partition
+      (DESIGN.md §3);
+    - otherwise a leaf-aligned layout, which :func:`init_state` lays out
+      on a TPU for f32 accumulators: one streamed, in-place pass per
+      gradient leaf.  A Pallas kernel reads the leaf in its own dtype,
       applies ``[reset ? 0 : acc] + g / n_good`` to A and B at once
       through ``input_output_aliases`` and emits both accumulators'
       per-tile Grams; the Grams are summed into distances after the
-      chain.  The layout starts every leaf on a kernel tile
-      (``make_layout(leaf_aligned=True)``; the gap columns stay zero), so
-      each leaf owns whole tiles.  The calls are chained on the aliased
-      buffers with no XLA op on A or B between them: an XLA update of a
-      slice between two calls makes the compiler keep a second copy of
-      both buffers.
-    - ``backend="pallas"``: one fused in-place chain of column-slice adds
-      into the buffer (the reset ``where`` is the only copy), then the
-      ``safeguard_filter`` Pallas Gram kernel over the whole buffer
-      (interpret mode on CPU with the package's ``ref.py`` as numerics
-      oracle); the CPU and campaign default.
-    - ``backend="xla"``: the same adds, then the oracle's fused f32
-      multiply-reduce; the choice under a sharded mesh (DESIGN.md §3).
+      chain.  Every leaf starts on a kernel tile (the gap columns stay
+      zero), so each leaf owns whole tiles.  The calls are chained on the
+      aliased buffers with no XLA op on A or B between them: an XLA update
+      of a slice between two calls makes the compiler keep a second copy
+      of both buffers;
+    - otherwise (off the TPU, or bf16 accumulators): one fused in-place
+      chain of column-slice adds into the buffer (the reset ``where`` is
+      the only copy), then the ``safeguard_filter`` Pallas Gram kernel
+      over the whole buffer (interpret mode off the TPU, with the
+      package's ``ref.py`` as numerics oracle).
   * **stacked** (paper-faithful reference): full stacked gradient pytrees,
     pairwise distances leaf-by-leaf via ``core.tree_utils.tree_gram``.
     Kept as the numerics oracle and for model-axis-sharded giants whose
@@ -73,6 +74,12 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def _fused_platform() -> bool:
+    """Whether the per-leaf accumulate+Gram kernel runs compiled here:
+    :func:`init_state` lays out a leaf-aligned state only then."""
+    return _on_tpu()
+
+
 # --------------------------------------------------------------------------
 # Flat buffer layout
 # --------------------------------------------------------------------------
@@ -98,6 +105,7 @@ class FlatLayout:
     sizes: Tuple[int, ...]
     d: int                            # true model dimension
     d_padded: int                     # d rounded up to a kernel-tile multiple
+    aligned: bool                     # every leaf starts on a kernel tile
 
 
 def _pad_multiple(d: int) -> int:
@@ -113,13 +121,18 @@ def _pad_multiple(d: int) -> int:
     return tile
 
 
-def make_layout(grads_like, *, leaf_aligned: bool = False) -> FlatLayout:
+def make_layout(grads_like) -> FlatLayout:
     """``grads_like``: a parameter pytree (NOT worker-stacked).  The feature
     axis is padded to a kernel-tile multiple (zeros never change
     distances), so every downstream op is tile-aligned with no per-step
-    re-padding.  ``leaf_aligned`` also rounds every leaf's offset up to that
-    tile, so each leaf owns whole tiles (the ``pallas_fused`` backend's
-    per-leaf pass); the gap columns stay zero."""
+    re-padding."""
+    return _make_layout(grads_like, aligned=False)
+
+
+def _make_layout(grads_like, aligned: bool) -> FlatLayout:
+    """:func:`make_layout`; ``aligned`` also rounds every leaf's offset up
+    to the tile, so each leaf owns whole tiles (the per-leaf
+    accumulate+Gram pass); the gap columns stay zero."""
     leaves, treedef = jax.tree_util.tree_flatten(grads_like)
     if not leaves:
         raise ValueError("empty gradient pytree")
@@ -135,14 +148,15 @@ def make_layout(grads_like, *, leaf_aligned: bool = False) -> FlatLayout:
     tile = _pad_multiple(d)
     off = 0
     for size in sizes:
-        if leaf_aligned:
+        if aligned:
             off += (-off) % tile
         offsets.append(off)
         off += size
     d_padded = off + (-off) % tile
     return FlatLayout(treedef=treedef, shapes=tuple(shapes),
                       dtypes=tuple(dtypes), offsets=tuple(offsets),
-                      sizes=tuple(sizes), d=d, d_padded=d_padded)
+                      sizes=tuple(sizes), d=d, d_padded=d_padded,
+                      aligned=aligned)
 
 
 def flatten_stacked(grads, layout: FlatLayout) -> jax.Array:
@@ -196,16 +210,6 @@ class SafeguardConfig:
     ``engine``:
       * ``"flat"`` — flat-buffer streaming accumulators (default);
       * ``"stacked"`` — paper-faithful stacked-pytree reference.
-    ``backend`` (flat engine only):
-      * ``"pallas"`` — in-place scatter accumulate + the blocked Pallas
-        Gram kernel (interpret mode off-TPU);
-      * ``"pallas_fused"`` — one in-place accumulate+Gram kernel call per
-        gradient leaf, A and B together, on a leaf-aligned layout (the
-        single-device TPU path); requires f32 accumulators;
-      * ``"xla"`` — in-place scatter accumulate + the oracle's fused f32
-        multiply-reduce (``kernels/safeguard_filter/ref.py``);
-        use under a sharded mesh where a single-device kernel cannot be
-        partitioned (DESIGN.md §3).
     """
     m: int                      # number of workers
     T0: int = 100               # short window length (steps)
@@ -230,9 +234,8 @@ class SafeguardConfig:
     sketch_k: int = 2048
     sketch_reps: int = 4
     sketch_seed: int = 0
-    # exact accumulators: state representation + distance implementation
+    # exact accumulators: state representation and dtype
     engine: str = "flat"        # "flat" | "stacked"
-    backend: str = "pallas"     # "pallas" | "xla"
     acc_dtype: Any = jnp.float32
 
     def __post_init__(self):
@@ -242,12 +245,6 @@ class SafeguardConfig:
             raise ValueError(f"bad rule {self.rule!r}")
         if self.engine not in ("flat", "stacked"):
             raise ValueError(f"bad engine {self.engine!r}")
-        if self.backend not in ("pallas", "pallas_fused", "xla"):
-            raise ValueError(f"bad backend {self.backend!r}")
-        if (self.backend == "pallas_fused"
-                and jnp.dtype(self.acc_dtype) != jnp.float32):
-            raise ValueError("backend 'pallas_fused' needs float32 "
-                             f"accumulators, got {jnp.dtype(self.acc_dtype)}")
         if self.T0 > self.T1:
             raise ValueError("need T0 <= T1")
         if self.rule == "theoretical" and self.thresh0 <= 0:
@@ -294,7 +291,10 @@ jax.tree_util.register_dataclass(
 
 
 def init_state(cfg: SafeguardConfig, grads_like) -> SafeguardState:
-    """``grads_like``: a parameter pytree (NOT stacked) used for shapes."""
+    """``grads_like``: a parameter pytree (NOT stacked) used for shapes.
+    The flat layout is leaf-aligned exactly where the per-leaf
+    accumulate+Gram kernel can run: compiled (:func:`_fused_platform`)
+    on f32 accumulators."""
     layout = None
     # A and B are always distinct buffers: a step that donates its state
     # may not receive one buffer twice
@@ -302,8 +302,9 @@ def init_state(cfg: SafeguardConfig, grads_like) -> SafeguardState:
         if cfg.use_sketch:
             shape, dtype = (cfg.m, cfg.sketch_reps * cfg.sketch_k), jnp.float32
         else:
-            layout = make_layout(
-                grads_like, leaf_aligned=cfg.backend == "pallas_fused")
+            layout = _make_layout(
+                grads_like, aligned=_fused_platform() and jnp.dtype(
+                    cfg.acc_dtype) == jnp.float32)
             shape, dtype = (cfg.m, layout.d_padded), cfg.acc_dtype
         A = jnp.zeros(shape, dtype) if cfg.mode == "double" else None
         B = jnp.zeros(shape, dtype)
@@ -398,31 +399,32 @@ def _accumulate_flat(acc, grads, reset, scale, layout: FlatLayout):
     return buf
 
 
-def _flat_sqdist(buf, cfg: SafeguardConfig):
-    """Pairwise squared distances of the flat accumulator: blocked Pallas
-    Gram kernel (one block under the CPU interpreter) or the oracle's XLA
-    multiply-reduce (shardable by XLA; on a ``(data=4, model=1)`` v5e mesh
-    it all-gathers the worker rows)."""
-    if cfg.backend == "pallas":
-        from repro.kernels.safeguard_filter import pairwise_sqdist
-        return pairwise_sqdist(buf, block_d=None, interpret=not _on_tpu())
-    from repro.kernels.safeguard_filter import ref as sf_ref
-    return sf_ref.pairwise_sqdist(buf)
+def _flat_sqdist(buf, sharded: bool):
+    """Pairwise squared distances of the flat accumulator: the oracle's
+    XLA multiply-reduce when the buffer is ``sharded`` (XLA partitions
+    it; on a ``(data=4, model=1)`` v5e mesh it all-gathers the worker
+    rows), else the blocked Pallas Gram kernel (one block under the CPU
+    interpreter)."""
+    if sharded:
+        from repro.kernels.safeguard_filter import ref as sf_ref
+        return sf_ref.pairwise_sqdist(buf)
+    from repro.kernels.safeguard_filter import pairwise_sqdist
+    return pairwise_sqdist(buf, block_d=None, interpret=not _on_tpu())
 
 
-def _flat_update(acc, grads, reset, scale, cfg: SafeguardConfig,
-                 layout: FlatLayout):
+def _flat_update(acc, grads, reset, scale, layout: FlatLayout,
+                 sharded: bool):
     """One accumulator's update -> (new_acc, sqdist), the accumulate under
     the scope ``accumulate`` and the distance pass under ``distance``."""
     with jax.named_scope("accumulate"):
         new = _accumulate_flat(acc, grads, reset, scale, layout)
     with jax.named_scope("distance"):
-        return new, _flat_sqdist(new, cfg)
+        return new, _flat_sqdist(new, sharded)
 
 
 def _fused_update(accs, grads, resets, scale, layout: FlatLayout):
-    """``backend="pallas_fused"``: every accumulator of ``accs`` (A and B,
-    or B alone) updated in place by one kernel call per gradient leaf, in
+    """The leaf-aligned layout's pass: every accumulator of ``accs`` (A and
+    B, or B alone) updated in place by one kernel call per gradient leaf, in
     ``tree_leaves`` order, each call also emitting the updated tiles'
     Grams; the Grams are summed into squared distances after the chain.
     Returns (new_accs, sqdists)."""
@@ -454,6 +456,10 @@ def safeguard_step(state: SafeguardState, grads, cfg: SafeguardConfig,
       acc_sharding: optional ``NamedSharding`` pinned onto the flat gradient
         buffer (and hence the accumulators) so the worker rows stay on the
         ``data`` mesh axes under a sharded jit (DESIGN.md §3).
+
+    The flat engine's distance pass follows from what it is given: the
+    XLA multiply-reduce under an ``acc_sharding``, else the per-leaf
+    accumulate+Gram kernel on a leaf-aligned layout, else the Gram kernel.
 
     Returns:
       (new_state, aggregated gradient pytree, info dict)
@@ -497,7 +503,8 @@ def safeguard_step(state: SafeguardState, grads, cfg: SafeguardConfig,
     elif cfg.engine == "flat":
         layout = state.layout
         A, sqdist_A = None, None
-        if cfg.backend == "pallas_fused":
+        sharded = acc_sharding is not None
+        if layout.aligned and not sharded:
             if cfg.mode == "double":
                 (A, B), (sqdist_A, sqdist_B) = _fused_update(
                     (state.A, state.B), grads,
@@ -507,11 +514,11 @@ def safeguard_step(state: SafeguardState, grads, cfg: SafeguardConfig,
                     (state.B,), grads, reset_B, inv_ngood, layout)
         else:
             B, sqdist_B = _flat_update(state.B, grads, reset_B, inv_ngood,
-                                       cfg, layout)
+                                       layout, sharded)
             if cfg.mode == "double":
                 A, sqdist_A = _flat_update(state.A, grads, reset_A,
-                                           inv_ngood, cfg, layout)
-        if acc_sharding is not None:
+                                           inv_ngood, layout, sharded)
+        if sharded:
             with jax.named_scope("accumulate"):
                 B = jax.lax.with_sharding_constraint(B, acc_sharding)
                 if A is not None:

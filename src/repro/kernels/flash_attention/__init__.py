@@ -1,4 +1,3 @@
-from repro.kernels.flash_attention.ops import flash_attention  # noqa: F401
 from repro.kernels.flash_attention.ops import (               # noqa: F401
     flash_attention_train, train_kernel_fits)
 from repro.kernels.flash_attention import ref                  # noqa: F401

@@ -1,43 +1,12 @@
-"""Jit-able flash-attention wrappers.
-
-``flash_attention``: the repo's forward-only kernel; picks MXU-aligned
-block sizes and pads the sequence (padded keys are masked out by
-causality since padded queries sit after all real queries and are sliced
-away).
-
-``flash_attention_train``: causal GQA attention of the train/prefill path,
-forward and backward as Pallas TPU kernels (JAX's ``splash_attention``).
-"""
+"""``flash_attention_train``: causal GQA attention of the train/prefill
+path, forward and backward as Pallas TPU kernels (JAX's
+``splash_attention``)."""
 
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
 from jax.experimental.pallas.ops.tpu import splash_attention as splash
-
-from repro.kernels.flash_attention.kernel import flash_attention_kernel
-
-
-@functools.partial(jax.jit, static_argnames=("window", "block_q", "block_k",
-                                             "interpret"))
-def flash_attention(q, k, v, *, window: int = 0, block_q: int = 128,
-                    block_k: int = 128, interpret: bool = True):
-    """q: (B, H, L, D); k, v: (B, K, L, D) -> (B, H, L, D)."""
-    B, H, L, D = q.shape
-    bq, bk = min(block_q, L), min(block_k, L)
-    pad = (-L) % max(bq, bk)
-    if pad:
-        zq = jnp.zeros((B, H, pad, D), q.dtype)
-        zk = jnp.zeros((B, k.shape[1], pad, D), k.dtype)
-        q = jnp.concatenate([q, zq], axis=2)
-        k = jnp.concatenate([k, zk], axis=2)
-        v = jnp.concatenate([v, zk], axis=2)
-    out = flash_attention_kernel(q, k, v, block_q=bq, block_k=bk,
-                                 window=window, interpret=interpret)
-    return out[:, :, :L]
-
 
 # Block sizes of the train kernels, chosen on a v5e at the Granite cell's
 # attention (4 workers of 4096 tokens, 24 query and 8 KV heads of 64;

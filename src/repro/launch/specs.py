@@ -13,8 +13,9 @@ allocation ever happens (``jax.eval_shape`` end to end):
 
 ``variant`` selects the aggregation implementation for §Perf:
   "exact"    — flat-buffer O(m*d) accumulators (f32, DESIGN.md §6), rows
-               sharded over the data axes (XLA backend — the Pallas kernel
-               is a per-device program and cannot be partitioned);
+               sharded over the data axes (so the XLA distance pass — the
+               Pallas kernels are per-device programs and cannot be
+               partitioned);
   "exact16"  — flat accumulators in bf16;
   "stacked"  — paper-faithful stacked-pytree accumulators (the reference
                representation; keeps per-leaf model-axis sharding);
@@ -47,7 +48,7 @@ from repro.train import trainer as tr
 def make_sg_cfg(m: int, variant: str = "exact") -> Optional[sg.SafeguardConfig]:
     if variant == "mean":
         return None
-    kwargs: Dict[str, Any] = dict(m=m, T0=100, T1=600, backend="xla")
+    kwargs: Dict[str, Any] = dict(m=m, T0=100, T1=600)
     if variant == "exact16":
         kwargs["acc_dtype"] = jnp.bfloat16
     if variant == "stacked":
@@ -75,14 +76,16 @@ def build_train(cfg: ModelConfig, shape: InputShape, mesh, *,
     else:
         defense = dfn_lib.from_aggregator(
             agg_lib.Aggregator("mean", agg_lib.mean))
+    params_a = T.init_abstract(cfg)
+    sg_a = (jax.eval_shape(functools.partial(sg.init_state, sg_cfg), params_a)
+            if sg_cfg is not None else None)
     acc_sharding = None
     if defense.flat_state:
         # flat (m, d_pad) defense state: worker rows on the data axes,
         # feature columns on model (DESIGN.md §3/§6) — one rule for every
         # flat-buffer defense, not a safeguard special case
-        layout = sg.make_layout(T.init_abstract(cfg))
         acc_sharding = NamedSharding(
-            mesh, sh.flat_acc_pspec(mesh, layout.d_padded))
+            mesh, sh.flat_acc_pspec(mesh, sg_a.layout.d_padded))
     step = tr.make_train_step(loss, opt, byz_mask=jnp.zeros((m,), bool),
                               defense=defense, spmd_axis_name=spmd,
                               acc_sharding=acc_sharding,
@@ -92,9 +95,6 @@ def build_train(cfg: ModelConfig, shape: InputShape, mesh, *,
                               trace_zeta=False, jit=False)
 
     # ---- abstract state with shardings --------------------------------
-    params_a = T.init_abstract(cfg)
-    sg_a = (jax.eval_shape(functools.partial(sg.init_state, sg_cfg), params_a)
-            if sg_cfg is not None else None)
     state_a = tr.TrainState(
         params=params_a, opt_state=jax.eval_shape(opt.init, params_a),
         defense_state=sg_a, attack_state=None,

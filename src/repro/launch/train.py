@@ -36,8 +36,8 @@ from repro.train import Trainer, init_train_state, make_train_step
 from repro import checkpoint as ckpt_lib
 
 
-def build_defense(name: str, m: int, n_byz: int, args,
-                  backend: str = "pallas") -> dfn_lib.Defense:
+def build_defense(name: str, m: int, n_byz: int,
+                  args) -> dfn_lib.Defense:
     """Any defense of the protocol registry (DESIGN.md §12);
     ``safeguard`` is an alias for ``safeguard_double``."""
     if name == "safeguard":
@@ -45,7 +45,7 @@ def build_defense(name: str, m: int, n_byz: int, args,
     reg = dfn_lib.make_registry(m, n_byz, T0=args.t0, T1=args.t1,
                                 threshold_floor=args.floor,
                                 reset_period=args.reset_period,
-                                use_sketch=args.sketch, backend=backend)
+                                use_sketch=args.sketch)
     if name not in reg:
         raise SystemExit(f"unknown defense {name}; "
                          f"choose safeguard|{sorted(reg)}")
@@ -112,20 +112,18 @@ def build_trainer(cfg, args, *, mesh=None) -> Trainer:
     ``(m, d_pad)`` accumulators are updated in place rather than held
     twice.  With a ``mesh`` (axes ``data``/``model``, one worker row per
     ``data`` shard) state and batches are placed by the rules of
-    ``launch.specs`` and the safeguard uses the shardable XLA distance pass
-    (DESIGN.md §3); without one everything lives on the default device and
-    the safeguard's accumulators and their Grams are updated by one
-    in-place Pallas pass per gradient leaf (``pallas_fused``, DESIGN.md
-    §6)."""
+    ``launch.specs`` and the safeguard's accumulators are pinned to it,
+    so it takes the shardable XLA distance pass (DESIGN.md §3); without
+    one everything lives on the default device, where on a TPU the
+    safeguard updates its f32 accumulators and their Grams by one
+    in-place Pallas pass per gradient leaf (DESIGN.md §6)."""
     m, n_byz = args.workers, args.byz
     if args.batch % m:
         raise SystemExit("--batch must be divisible by --workers")
     byz_mask = jnp.arange(m) < n_byz
 
     attack = atk_lib.make_registry()[args.attack]
-    defense = build_defense(args.defense, m, n_byz, args,
-                            backend="pallas_fused" if mesh is None
-                            else "xla")
+    defense = build_defense(args.defense, m, n_byz, args)
 
     opt = make_optimizer(TrainConfig(lr=args.lr, momentum=args.momentum,
                                      optimizer=args.optimizer))

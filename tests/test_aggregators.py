@@ -29,15 +29,28 @@ def test_mean(grads):
                                rtol=1e-5)
 
 
-def test_coordinate_median(grads):
+@pytest.mark.parametrize("m,d,dtype", [(M, (D1, D2), jnp.float32)] + [
+    (m, (d,), dtype) for m, d in [(5, 128), (10, 1000), (16, 4096), (9, 257),
+                                  (8, 130)]
+    for dtype in (jnp.float32, jnp.bfloat16)])
+def test_coordinate_median(m, d, dtype, rng):
+    """Odd and even m, ragged d, f32 and bf16 (the median of the f32 values,
+    rounded once to the input dtype)."""
+    grads = {"a": jax.random.normal(rng, (m,) + d, dtype)}
     out = agg.coordinate_median(grads)
-    want = np.median(np.asarray(grads["a"]), axis=0)
-    np.testing.assert_allclose(np.asarray(out["a"]), want, atol=1e-6)
+    want = np.median(np.asarray(grads["a"], np.float32), axis=0)
+    assert out["a"].dtype == dtype
+    np.testing.assert_array_equal(
+        np.asarray(out["a"], np.float32),
+        np.asarray(jnp.asarray(want).astype(dtype), np.float32))
 
 
-def test_trimmed_mean(grads):
-    out = agg.trimmed_mean(grads, trim=2)
-    s = np.sort(np.asarray(grads["a"]), axis=0)[2:M - 2]
+@pytest.mark.parametrize("m,d,trim", [(M, (D1, D2), 2), (10, (512,), 2),
+                                      (16, (1000,), 4), (7, (129,), 1)])
+def test_trimmed_mean(m, d, trim, rng):
+    g = jax.random.normal(rng, (m,) + d)
+    out = agg.trimmed_mean({"a": g}, trim=trim)
+    s = np.sort(np.asarray(g), axis=0)[trim:m - trim]
     np.testing.assert_allclose(np.asarray(out["a"]), s.mean(0), atol=1e-5)
 
 

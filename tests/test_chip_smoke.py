@@ -136,13 +136,6 @@ def test_safeguard_state_can_be_donated(mode, sketch):
     assert int(st.step) == 1
 
 
-def test_fused_backend_refuses_non_f32_accumulators():
-    with pytest.raises(ValueError, match="pallas_fused"):
-        sg.SafeguardConfig(m=4, backend="pallas_fused",
-                           acc_dtype=jnp.bfloat16)
-    sg.SafeguardConfig(m=4, backend="pallas_fused")        # f32 is fine
-
-
 def test_compile_cache_honours_env(monkeypatch):
     before = jax.config.jax_compilation_cache_dir
     monkeypatch.setenv(compile_cache.ENV_VAR, "/elsewhere/cache")

@@ -8,10 +8,6 @@ import pytest
 
 from repro.kernels.safeguard_filter import pairwise_sqdist
 from repro.kernels.safeguard_filter import ref as sf_ref
-from repro.kernels.robust_agg import coord_median, trimmed_mean
-from repro.kernels.robust_agg import ref as ra_ref
-from repro.kernels.flash_attention import flash_attention
-from repro.kernels.flash_attention import ref as fa_ref
 
 
 # --------------------------------------------------------------------------
@@ -36,70 +32,6 @@ def test_pairwise_sqdist_symmetry(rng):
     a = jax.random.normal(rng, (12, 777))
     out = np.asarray(pairwise_sqdist(a))
     np.testing.assert_allclose(out, out.T, atol=1e-4)
-
-
-# --------------------------------------------------------------------------
-# robust_agg
-# --------------------------------------------------------------------------
-
-@pytest.mark.parametrize("m,d", [(5, 128), (10, 1000), (16, 4096),
-                                 (9, 257), (8, 130)])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_coord_median_sweep(m, d, dtype, rng):
-    g = jax.random.normal(rng, (m, d), dtype)
-    out = coord_median(g)
-    want = ra_ref.coord_median(g)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-5)
-
-
-@pytest.mark.parametrize("m,d,trim", [(10, 512, 2), (16, 1000, 4),
-                                      (7, 129, 1)])
-def test_trimmed_mean_sweep(m, d, trim, rng):
-    g = jax.random.normal(rng, (m, d))
-    out = trimmed_mean(g, trim=trim)
-    want = ra_ref.trimmed_mean(g, trim)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
-                               atol=1e-5)
-
-
-def test_trimmed_mean_overtrim_raises(rng):
-    with pytest.raises(ValueError):
-        trimmed_mean(jax.random.normal(rng, (4, 128)), trim=2)
-
-
-# --------------------------------------------------------------------------
-# flash_attention
-# --------------------------------------------------------------------------
-
-@pytest.mark.parametrize("B,H,K,L,D,win,bq,bk", [
-    (1, 4, 4, 256, 64, 0, 64, 64),     # MHA
-    (2, 8, 2, 128, 64, 0, 64, 32),     # GQA
-    (1, 4, 1, 256, 64, 96, 64, 64),    # MQA + sliding window
-    (2, 2, 2, 200, 32, 0, 64, 64),     # padded sequence
-    (1, 2, 2, 128, 128, 0, 128, 128),  # single block
-])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_flash_attention_sweep(B, H, K, L, D, win, bq, bk, dtype, rng):
-    ks = jax.random.split(rng, 3)
-    q = jax.random.normal(ks[0], (B, H, L, D), dtype)
-    k = jax.random.normal(ks[1], (B, K, L, D), dtype)
-    v = jax.random.normal(ks[2], (B, K, L, D), dtype)
-    out = flash_attention(q, k, v, window=win, block_q=bq, block_k=bk)
-    want = fa_ref.attention(q, k, v, window=win)
-    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(want, np.float32), atol=tol)
-    assert out.dtype == dtype
-
-
-def test_flash_attention_first_row_attends_self_only(rng):
-    B, H, L, D = 1, 1, 128, 32
-    q = jax.random.normal(rng, (B, H, L, D))
-    k = jax.random.normal(jax.random.fold_in(rng, 1), (B, H, L, D))
-    v = jax.random.normal(jax.random.fold_in(rng, 2), (B, H, L, D))
-    out = flash_attention(q, k, v, block_q=64, block_k=64)
-    np.testing.assert_allclose(np.asarray(out[0, 0, 0]),
-                               np.asarray(v[0, 0, 0]), rtol=1e-5)
 
 
 # --------------------------------------------------------------------------

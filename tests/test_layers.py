@@ -195,19 +195,61 @@ def test_ring_from_full_short_seq(rng):
 # Blocked flash (jnp)
 # --------------------------------------------------------------------------
 
-@pytest.mark.parametrize("window", [0, 48])
-def test_flash_jnp_vs_dense(window, rng):
-    B, H, Lq, D = 2, 4, 128, 32
+def _flash_cases():
+    """(B, H, K, Lq, D, window, block_q, block_k, dtype, scale): MHA with
+    and without a window at scale 0.2, then at the oracle's 1/sqrt(D) in
+    f32 and bf16: MHA, GQA (H/K = 4), MQA with a window, a ragged
+    sequence (200, no multiple of the block) and a single block of D =
+    128."""
+    shapes = [(1, 4, 4, 256, 64, 0, 64, 64), (2, 8, 2, 128, 64, 0, 64, 32),
+              (1, 4, 1, 256, 64, 96, 64, 64), (2, 2, 2, 200, 32, 0, 64, 64),
+              (1, 2, 2, 128, 128, 0, 128, 128)]
+    cases = [pytest.param(2, 4, 4, 128, 32, w, 32, 32, f32, 0.2, id=str(w))
+             for w in (0, 48)]
+    for dtype in (f32, jnp.bfloat16):
+        for shape in shapes:
+            B, H, K, Lq, D, w = shape[:6]
+            cases.append(pytest.param(
+                *shape, dtype, D ** -0.5,
+                id=f"B{B}H{H}K{K}L{Lq}D{D}w{w}-{jnp.dtype(dtype).name}"))
+    return cases
+
+
+@pytest.mark.parametrize("B,H,K,Lq,D,window,block_q,block_k,dtype,scale",
+                         _flash_cases())
+def test_flash_jnp_vs_dense(B, H, K, Lq, D, window, block_q, block_k, dtype,
+                            scale, rng):
+    """``flash_attention_jnp`` (K/V expanded to H heads, as
+    ``attn_block_apply`` calls it) against the dense causal oracle of
+    ``kernels/flash_attention/ref.py``, the scale folded into q."""
+    from repro.kernels.flash_attention import ref as fa_ref
     ks = jax.random.split(rng, 3)
-    q = jax.random.normal(ks[0], (B, Lq, H, D))
-    k = jax.random.normal(ks[1], (B, Lq, H, D))
-    v = jax.random.normal(ks[2], (B, Lq, H, D))
-    out = L.flash_attention_jnp(q, k, v, scale=0.2, window=window,
-                                block_q=32, block_k=32)
-    mask = L.causal_mask(Lq, Lq, window=window)[None, None, None]
-    want = L.attention(q, k, v, scale=0.2, mask=mask).reshape(B, Lq, H, D)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
-                               rtol=1e-4, atol=1e-5)
+    q = jax.random.normal(ks[0], (B, Lq, H, D), dtype)
+    k = jax.random.normal(ks[1], (B, Lq, K, D), dtype)
+    v = jax.random.normal(ks[2], (B, Lq, K, D), dtype)
+    rep = lambda x: jnp.repeat(x, H // K, axis=2)
+    out = L.flash_attention_jnp(q, rep(k), rep(v), scale=scale,
+                                window=window, block_q=block_q,
+                                block_k=block_k)
+    heads_major = lambda x: jnp.swapaxes(x, 1, 2)
+    want = heads_major(fa_ref.attention(
+        heads_major(q * jnp.asarray(scale * D ** 0.5, dtype)),
+        heads_major(k), heads_major(v), window=window))
+    assert out.dtype == dtype
+    atol = 2e-2 if dtype == jnp.bfloat16 else 1e-5
+    np.testing.assert_allclose(np.asarray(out, f32), np.asarray(want, f32),
+                               atol=atol)
+
+
+def test_flash_jnp_first_row_attends_self_only(rng):
+    B, H, Lq, D = 1, 1, 128, 32
+    q = jax.random.normal(rng, (B, Lq, H, D))
+    k = jax.random.normal(jax.random.fold_in(rng, 1), (B, Lq, H, D))
+    v = jax.random.normal(jax.random.fold_in(rng, 2), (B, Lq, H, D))
+    out = L.flash_attention_jnp(q, k, v, scale=D ** -0.5, block_q=64,
+                                block_k=64)
+    np.testing.assert_allclose(np.asarray(out[0, 0, 0]),
+                               np.asarray(v[0, 0, 0]), rtol=1e-5)
 
 
 def test_flash_jnp_grad_matches_dense(rng):

@@ -191,18 +191,23 @@ def test_ring_from_full_property(L, S):
 
 # ------------------------------------------------- Defense protocol zoo
 
-# Backends exercised for the safeguard-family defenses: the Pallas Gram
-# kernel (interpret mode on CPU) and the sharded-mesh XLA dot path.
-_SG_BACKENDS = ("pallas", "xla")
+# Distance passes exercised for the safeguard-family defenses: the Pallas
+# Gram kernel (interpret mode on CPU) and the sharded-mesh XLA dot path,
+# which the step takes when its accumulators are pinned to a sharding.
+_SG_BACKENDS = ("gram", "xla")
 
 
-def _registry_for(m, n_byz, backend="pallas"):
-    reg = dfn.make_registry(m, n_byz, T0=4, T1=8, threshold_floor=0.5)
-    for name in ("safeguard_single", "safeguard_double"):
-        cfg = SafeguardConfig(m=m, T0=4, T1=8, threshold_floor=0.5,
-                              mode=name.split("_")[1], backend=backend)
-        reg[name] = dfn.make_safeguard_defense(cfg, name)
-    return reg
+def _registry_for(m, n_byz):
+    return dfn.make_registry(m, n_byz, T0=4, T1=8, threshold_floor=0.5)
+
+
+def _sg_ctx(backend):
+    """The defense ctx that puts the safeguard on ``backend``'s pass."""
+    if backend == "gram":
+        return {}
+    mesh = jax.sharding.Mesh(jax.devices()[:1], ("data",))
+    return {"acc_sharding": jax.sharding.NamedSharding(
+        mesh, jax.sharding.PartitionSpec())}
 
 
 def _normal_stack(m, d, seed):
@@ -222,19 +227,19 @@ def _clustered_stack(m, d, seed, outliers=2):
     return base.at[:outliers].add(5.0)
 
 
-def _run_steps(d, mat, perm=None, steps=2):
+def _run_steps(d, mat, perm=None, steps=2, backend="gram"):
     """Run ``steps`` aggregations (state warms up), permuting the worker
-    rows of every input by ``perm``."""
+    rows of every input by ``perm``; the safeguard on ``backend``."""
     state = (d.init_state({"w": jnp.zeros((mat.shape[1],))})
              if d.init_state else None)
-    ctx = {}
+    ctx = _sg_ctx(backend)
     for t in range(steps):
         g = mat + 0.1 * t
         if perm is not None:
             g = g[perm]
         if d.needs_held_batch:
             scores = -jnp.sum(g.astype(jnp.float32) ** 2, axis=1)
-            ctx = {"scores": scores}
+            ctx = {**ctx, "scores": scores}
         agg_out, state, info = d.aggregate(state, {"w": g}, ctx)
     return agg_out, info
 
@@ -251,15 +256,16 @@ def test_every_registry_defense_permutation_equivariant(m, seed, pseed):
     # n_byz=1 keeps Krum's neighborhood k = m - b - 2 >= 2: at k = 1
     # mutual nearest neighbors tie EXACTLY (the same symmetric distance),
     # and argmin tie-breaks are index-order-dependent by construction
-    regs = [_registry_for(m, 1, b) for b in _SG_BACKENDS]
+    reg = _registry_for(m, 1)
     seen = set()
-    for reg in regs:
+    for backend in _SG_BACKENDS:
         for name, d in reg.items():
             if name in seen and not name.startswith("safeguard"):
                 continue
             seen.add(name)
-            agg_base, info_base = _run_steps(d, mat)
-            agg_perm, info_perm = _run_steps(d, mat, perm=perm)
+            agg_base, info_base = _run_steps(d, mat, backend=backend)
+            agg_perm, info_perm = _run_steps(d, mat, perm=perm,
+                                             backend=backend)
             np.testing.assert_allclose(
                 np.asarray(agg_base["w"]), np.asarray(agg_perm["w"]),
                 rtol=2e-4, atol=2e-5, err_msg=name)
@@ -285,11 +291,11 @@ def test_robust_defenses_bound_single_byzantine_row(m, seed, mag):
     safeguard backends."""
     mat = _normal_stack(m, 6, seed)
     adv = mat.at[0].set(mag)
+    reg = _registry_for(m, 1)
     for backend in _SG_BACKENDS:
-        reg = _registry_for(m, 1, backend)
         for name in _ROBUST:
-            agg_clean, _ = _run_steps(reg[name], mat)
-            agg_adv, _ = _run_steps(reg[name], adv)
+            agg_clean, _ = _run_steps(reg[name], mat, backend=backend)
+            agg_adv, _ = _run_steps(reg[name], adv, backend=backend)
             shift = float(jnp.linalg.norm(agg_adv["w"] - agg_clean["w"]))
             honest = float(jnp.linalg.norm(mat[1:], axis=1).max())
             assert np.isfinite(shift), (name, backend)

@@ -1,13 +1,18 @@
 """Flat-buffer safeguard engine (DESIGN.md §6) equivalence suite: the
 flat engine must reproduce the stacked-pytree reference bit-for-bit in
 its *decisions* (eviction masks, eviction times, medians) and match the
-aggregate numerically, across mode x rule x reset-period x backend; plus
-layout round-trips and the fused-kernel oracle."""
+aggregate numerically, across mode x rule x reset-period x distance
+pass; plus the rule that picks the pass, layout round-trips and the
+fused-kernel oracle."""
+
+import re
+from unittest import mock
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import SafeguardConfig, init_state, safeguard_step
 from repro.core import attacks as atk
@@ -29,11 +34,38 @@ def honest_grads(key, mu=1.0, sigma=0.05):
         PARAMS)
 
 
-def run(cfg, attack_fn, byz_mask, steps, seed=0):
-    st = init_state(cfg, PARAMS)
+# The safeguard's state representations and distance passes, each reached
+# as the step reaches it: ``gram`` is the flat engine off the TPU, ``fused``
+# its state laid out as on a TPU (the kernel still interpreted here),
+# ``xla`` its accumulators pinned to a sharding.
+ENGINE_GRID = ["stacked", "gram", "xla", "fused"]
+
+
+def _one_device():
+    return NamedSharding(Mesh(jax.devices()[:1], ("data",)), P())
+
+
+def path_cfg(path, **kwargs):
+    return SafeguardConfig(engine="stacked" if path == "stacked" else "flat",
+                           **kwargs)
+
+
+def path_state(cfg, path, params=PARAMS):
+    """``init_state`` on the platform ``path`` needs: a TPU for ``fused``."""
+    with mock.patch.object(sg, "_fused_platform", lambda: path == "fused"):
+        return init_state(cfg, params)
+
+
+def path_step(cfg, path):
+    shd = _one_device() if path == "xla" else None
+    return jax.jit(lambda s, g: safeguard_step(s, g, cfg, acc_sharding=shd))
+
+
+def run(cfg, attack_fn, byz_mask, steps, seed=0, path="gram"):
+    st = path_state(cfg, path)
     key = jax.random.PRNGKey(seed)
     astate = None
-    step = jax.jit(lambda s, g: safeguard_step(s, g, cfg))
+    step = path_step(cfg, path)
     agg = None
     for t in range(steps):
         key, k = jax.random.split(key)
@@ -41,10 +73,6 @@ def run(cfg, attack_fn, byz_mask, steps, seed=0):
         g, astate = attack_fn(g, byz_mask, astate, jnp.int32(t), k)
         st, agg, info = step(st, g)
     return st, agg, info
-
-
-ENGINE_GRID = [("stacked", "pallas"), ("flat", "pallas"), ("flat", "xla"),
-               ("flat", "pallas_fused")]
 
 
 @pytest.mark.parametrize("mode", ["double", "single"])
@@ -58,12 +86,11 @@ def test_flat_matches_stacked_decisions(mode, rule):
         t0, t1 = SafeguardConfig.theoretical_thresholds(20, 60, M, V=0.2)
         kwargs.update(thresh0=t0, thresh1=t1)
     outs = {}
-    for engine, backend in ENGINE_GRID:
-        cfg = SafeguardConfig(engine=engine, backend=backend, **kwargs)
-        st, agg, info = run(cfg, atk.attack_sign_flip, byz, 60)
-        outs[(engine, backend)] = (st, agg, info)
+    for path in ENGINE_GRID:
+        outs[path] = run(path_cfg(path, **kwargs), atk.attack_sign_flip,
+                         byz, 60, path=path)
 
-    ref_st, ref_agg, ref_info = outs[("stacked", "pallas")]
+    ref_st, ref_agg, ref_info = outs["stacked"]
     assert bool((~ref_st.good[:4]).all()), "attack must be caught"
     for key, (st, agg, info) in outs.items():
         np.testing.assert_array_equal(np.asarray(st.good),
@@ -80,13 +107,11 @@ def test_flat_matches_stacked_with_reset_period():
     byz = jnp.arange(M) < 3
     attack = atk.make_burst(start=0, length=10, burst_scale=5.0)
     outs = {}
-    for engine, backend in ENGINE_GRID:
-        cfg = SafeguardConfig(m=M, T0=10, T1=20, threshold_floor=0.5,
-                              reset_period=30, engine=engine,
-                              backend=backend)
-        st, _, _ = run(cfg, attack, byz, 35)
-        outs[(engine, backend)] = st
-    ref = outs[("stacked", "pallas")]
+    for path in ENGINE_GRID:
+        cfg = path_cfg(path, m=M, T0=10, T1=20, threshold_floor=0.5,
+                       reset_period=30)
+        outs[path], _, _ = run(cfg, attack, byz, 35, path=path)
+    ref = outs["stacked"]
     assert bool(ref.good.all()), "reset must restore workers"
     for key, st in outs.items():
         np.testing.assert_array_equal(np.asarray(st.good),
@@ -127,9 +152,9 @@ def test_sqdist_producers_clamp_at_zero(mag, rng):
 def test_near_duplicate_grads_no_nan_and_identical_decisions():
     """NaN regression through the full safeguard step: near-duplicate
     large-magnitude gradients drive the accumulator rows into the f32
-    cancellation regime on every backend (and the sketched path); no
+    cancellation regime on every distance pass (and the sketched path); no
     distance may go NaN, no honest worker may be evicted, and all
-    backends must agree on the decisions bit-for-bit.
+    passes must agree on the decisions bit-for-bit.
 
     The threshold floor sits well above the f32 cancellation noise
     (distances here are ~pure rounding error, a few units at mu=1e3):
@@ -146,28 +171,27 @@ def test_near_duplicate_grads_no_nan_and_identical_decisions():
                 next(ks), (M,) + p.shape)), PARAMS)
 
     outs = {}
-    grid = ENGINE_GRID + [("sketch", "pallas")]
-    for engine, backend in grid:
+    for path in ENGINE_GRID + ["sketch"]:
         kwargs = dict(m=M, T0=20, T1=60, threshold_floor=100.0)
-        if engine == "sketch":
+        if path == "sketch":
             cfg = SafeguardConfig(use_sketch=True, sketch_k=512,
                                   sketch_reps=4, **kwargs)
         else:
-            cfg = SafeguardConfig(engine=engine, backend=backend, **kwargs)
-        st = init_state(cfg, PARAMS)
+            cfg = path_cfg(path, **kwargs)
+        st = path_state(cfg, path)
         key = jax.random.PRNGKey(0)
-        step = jax.jit(lambda s, g, c=cfg: safeguard_step(s, g, c))
+        step = path_step(cfg, path)
         for t in range(10):
             key, k = jax.random.split(key)
             st, agg, info = step(st, near_dup_grads(k))
             assert bool(jnp.isfinite(info["dist_to_med_B"]).all()), \
-                (engine, backend, t)
-            assert bool(jnp.isfinite(info["threshold_B"])), (engine, backend)
-        assert bool(st.good.all()), (engine, backend)
+                (path, t)
+            assert bool(jnp.isfinite(info["threshold_B"])), path
+        assert bool(st.good.all()), path
         for leaf in jax.tree_util.tree_leaves(agg):
-            assert bool(jnp.isfinite(leaf).all()), (engine, backend)
-        outs[(engine, backend)] = np.asarray(st.good)
-    ref = outs[("stacked", "pallas")]
+            assert bool(jnp.isfinite(leaf).all()), path
+        outs[path] = np.asarray(st.good)
+    ref = outs["stacked"]
     for k, good in outs.items():
         np.testing.assert_array_equal(good, ref, err_msg=str(k))
 
@@ -217,12 +241,16 @@ def test_layout_static_and_round_trip():
 
 
 def test_leaf_aligned_layout_round_trip_and_row_norms():
-    """The ``pallas_fused`` layout starts every leaf on a tile boundary:
-    rows round-trip through ``unflatten_row``, the gap columns stay zero,
-    and per-leaf row norms of ``B`` read by ``offsets``/``sizes`` slices
-    equal the stacked engine's per-leaf norms after one step."""
-    lay = sg.make_layout(PARAMS, leaf_aligned=True)
+    """The layout of f32 accumulators on a TPU starts every leaf on a tile
+    boundary: rows round-trip through ``unflatten_row``, the gap columns
+    stay zero, and per-leaf row norms of ``B`` read by ``offsets``/``sizes``
+    slices equal the stacked engine's per-leaf norms after one step."""
+    cfg = dict(m=M, T0=20, T1=60, threshold_floor=0.5)
+    fused = path_cfg("fused", **cfg)
+    st_f = path_state(fused, "fused")
+    lay = st_f.layout
     tile = sg._pad_multiple(lay.d)
+    assert lay.aligned and not sg.make_layout(PARAMS).aligned
     assert lay.sizes == sg.make_layout(PARAMS).sizes
     assert all(off % tile == 0 for off in lay.offsets)
     assert lay.d_padded % tile == 0
@@ -234,10 +262,8 @@ def test_leaf_aligned_layout_round_trip_and_row_norms():
     jax.tree.map(lambda a, b: np.testing.assert_array_equal(
         np.asarray(a), np.asarray(b[4])), back, g)
 
-    cfg = dict(m=M, T0=20, T1=60, threshold_floor=0.5)
-    fused = SafeguardConfig(backend="pallas_fused", **cfg)
     stacked = SafeguardConfig(engine="stacked", **cfg)
-    st_f, _, _ = safeguard_step(init_state(fused, PARAMS), g, fused)
+    st_f, _, _ = safeguard_step(st_f, g, fused)
     st_s, _, _ = safeguard_step(init_state(stacked, PARAMS), g, stacked)
     assert st_f.layout == lay
     B = np.asarray(st_f.B)
@@ -257,7 +283,7 @@ def _aligned(m, shapes, key, dtype=jnp.float32):
     worker-stacked gradients and accumulators on it (zero gap columns, as
     the layout keeps them)."""
     params = {f"p{i}": jnp.zeros(s) for i, s in enumerate(shapes)}
-    lay = sg.make_layout(params, leaf_aligned=True)
+    lay = path_state(SafeguardConfig(m=m), "fused", params).layout
     ks = jax.random.split(key, 3 * len(shapes))
     stack = lambda k0: jax.tree.map(
         lambda p, k: jax.random.normal(k, (m,) + p.shape), params,
@@ -350,7 +376,31 @@ def test_flat_state_shapes_and_dtype():
     st = init_state(cfg, PARAMS)
     assert st.B.shape == (M, st.layout.d_padded)
     assert st.B.dtype == jnp.bfloat16
-    # bf16 accumulators fall back to the XLA distance path and still run
+    # bf16 accumulators take the Gram kernel, on a TPU too, and still run
     g = honest_grads(jax.random.PRNGKey(0))
     st2, _, _ = jax.jit(lambda s, gr: safeguard_step(s, gr, cfg))(st, g)
     assert st2.B.dtype == jnp.bfloat16
+
+
+@pytest.mark.parametrize("tpu,dtype,pinned,kernel", [
+    (False, jnp.float32, False, "pairwise_sqdist_kernel"),
+    (True, jnp.float32, False, "fused_accumulate_sqdist_kernel"),
+    (True, jnp.bfloat16, False, "pairwise_sqdist_kernel"),
+    (True, jnp.float32, True, None),
+    (False, jnp.float32, True, None),
+], ids=["cpu-f32", "tpu-f32", "tpu-bf16", "tpu-f32-sharded",
+        "cpu-f32-sharded"])
+def test_step_picks_its_distance_pass(tpu, dtype, pinned, kernel):
+    """The flat step's distance pass follows from what it observes: the
+    XLA multiply-reduce (no Pallas kernel) for accumulators pinned to a
+    sharding, else the per-leaf accumulate+Gram kernel where the state was
+    laid out on a TPU for f32, else the Gram kernel."""
+    cfg = SafeguardConfig(m=M, T0=20, T1=60, acc_dtype=dtype)
+    with mock.patch.object(sg, "_fused_platform", lambda: tpu):
+        st = init_state(cfg, PARAMS)
+    shd = _one_device() if pinned else None
+    jaxpr = jax.make_jaxpr(
+        lambda s, g: safeguard_step(s, g, cfg, acc_sharding=shd))(
+            st, honest_grads(jax.random.PRNGKey(0)))
+    kernels = set(re.findall(r"\b(\w+_kernel)\b", str(jaxpr)))
+    assert kernels == ({kernel} if kernel else set())

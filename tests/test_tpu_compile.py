@@ -47,23 +47,25 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _smoke_layout(leaf_aligned: bool = False) -> sg.FlatLayout:
-    return sg.make_layout(T.init_abstract(cs.model_config()),
-                          leaf_aligned=leaf_aligned)
+def _smoke_layout(fused: bool = False) -> sg.FlatLayout:
+    """The safeguard's layout of the smoke model, as ``init_state`` lays
+    it out for the fused pass (on a TPU) or for the Gram kernel."""
+    cfg = sg.SafeguardConfig(m=cs.M)
+    with mock.patch.object(sg, "_fused_platform", lambda: fused):
+        return jax.eval_shape(functools.partial(sg.init_state, cfg),
+                              T.init_abstract(cs.model_config())).layout
 
 
-def _compile_smoke_step(one_chip, backend: str):
+def _compile_smoke_step(one_chip, fused: bool):
     """The donated smoke step of ``chip_smoke.py``, built through
-    ``build_trainer`` with the safeguard on ``backend``, compiled for one
-    v5e chip.  Traced, so the full-width state is only shapes."""
+    ``build_trainer`` with the safeguard's state laid out for the fused
+    pass (as on a TPU) or for the Gram kernel, compiled for one v5e chip.
+    Traced, so the full-width state is only shapes."""
     built = {}
-    build_defense = cs.train_lib.build_defense
 
     def build():
         with mock.patch.object(sg, "_on_tpu", lambda: True), \
-                mock.patch.object(cs.train_lib, "build_defense",
-                                  lambda *a, **kw: build_defense(
-                                      *a, **{**kw, "backend": backend})):
+                mock.patch.object(sg, "_fused_platform", lambda: fused):
             built["trainer"] = cs.train_lib.build_trainer(cs.model_config(),
                                                           cs.smoke_args())
         return built["trainer"].state
@@ -78,15 +80,14 @@ def _compile_smoke_step(one_chip, backend: str):
 
 @pytest.fixture(scope="module")
 def smoke_step(one_chip):
-    """``smoke_step(backend)``: the compiled smoke step, once per backend."""
+    """``smoke_step(fused)``: the compiled smoke step, once per layout."""
     return functools.cache(functools.partial(_compile_smoke_step, one_chip))
 
 
 @pytest.mark.parametrize("kernel", ["pairwise_sqdist",
                                     "fused_accumulate_sqdist"])
 def test_kernel_compiles_for_v5e_at_smoke_width(one_chip, kernel):
-    leaf_aligned = kernel == "fused_accumulate_sqdist"
-    lay = _smoke_layout(leaf_aligned)
+    lay = _smoke_layout(fused=kernel == "fused_accumulate_sqdist")
     buf = jax.ShapeDtypeStruct((cs.M, lay.d_padded), jnp.float32,
                                sharding=one_chip)
     if kernel == "pairwise_sqdist":
@@ -112,7 +113,7 @@ def test_kernel_compiles_for_v5e_at_smoke_width(one_chip, kernel):
 
 
 def test_smoke_step_compiles_for_v5e_and_fits(smoke_step):
-    compiled = smoke_step("pallas_fused")     # build_trainer's on one chip
+    compiled = smoke_step(True)     # build_trainer's on one chip
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
     # the state is donated: the outputs live in the arguments' buffers
@@ -126,17 +127,17 @@ def test_smoke_step_compiles_for_v5e_and_fits(smoke_step):
 
 def test_fused_smoke_step_has_no_gram_kernel_or_accumulator_copy(
         smoke_step):
-    """``pallas_fused`` updates A and B in place and forms their Grams in
+    """The fused pass updates A and B in place and forms their Grams in
     the same pass: the step runs no separate Gram kernel, copies no
     ``f32[m, d_pad]`` accumulator, and needs fewer temporaries than the
-    ``pallas`` backend's scatter-and-Gram step."""
-    fused, scatter = smoke_step("pallas_fused"), smoke_step("pallas")
+    scatter-and-Gram step of a state laid out off the TPU."""
+    fused, scatter = smoke_step(True), smoke_step(False)
     text = fused.as_text()
     kernels = lambda name: re.findall(
         rf"^\s*%?{name}\.\d+ = .* custom-call\(", text, flags=re.M)
     assert kernels("fused_accumulate_sqdist_kernel")
     assert not kernels("pairwise_sqdist_kernel")
-    d_pad = _smoke_layout(leaf_aligned=True).d_padded
+    d_pad = _smoke_layout(fused=True).d_padded
     copies = re.findall(rf"= f32\[{cs.M},{d_pad}\]\S* copy\(", text)
     assert not copies
     assert (fused.memory_analysis().temp_size_in_bytes
