@@ -644,11 +644,12 @@ def _ragged_dot_t(lhs, rhs, group_sizes):
 
 def _lanes_in_turn(f):
     """``f`` under ``vmap`` runs each lane in turn: the TPU's ragged dot
-    takes no batch dimension.  Where the context mesh (which
-    ``launch.train.build_trainer`` sets around its step) splits the lanes
-    over its worker axes, as the trainer's worker ``vmap`` is split, each
-    shard runs its own lanes: a loop over the whole axis would have every
-    shard gather and compute every worker's."""
+    takes no batch dimension, and a branch on a lane's own values stays a
+    branch (``vmap`` makes it a select of every branch).  Where the
+    context mesh (which ``launch.train.build_trainer`` sets around its
+    step) splits the lanes over its worker axes, as the trainer's worker
+    ``vmap`` is split, each shard runs its own lanes: a loop over the
+    whole axis would have every shard gather and compute every worker's."""
     f = jax.custom_batching.custom_vmap(f)
 
     @f.def_vmap
@@ -665,10 +666,19 @@ def _lanes_in_turn(f):
         axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
         n = math.prod(mesh.shape[a] for a in axes)
         if n > 1 and axis_size % n == 0:
-            lanes = jax.shard_map(
-                lanes, mesh=mesh, out_specs=_P(axes),
-                in_specs=tuple(_P(axes) if b else _P() for b in in_batched))
-        return lanes(*args), True
+            # what the lanes share reaches each shard as varying over the
+            # worker axes, so that a lane may take its own cotangent of it
+            def shard(*args):
+                return lanes(*[a if b else jax.lax.pcast(a, axes,
+                                                         to="varying")
+                               for a, b in zip(args, in_batched)])
+            out = jax.shard_map(
+                shard, mesh=mesh, out_specs=_P(axes),
+                in_specs=tuple(_P(axes) if b else _P() for b in in_batched)
+            )(*args)
+        else:
+            out = lanes(*args)
+        return out, jax.tree.map(lambda _: True, out)
 
     return f
 
@@ -758,6 +768,87 @@ def _combine_bwd(res, g):
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
+def _expert_rows(xt, weights, assign, slot, group_sizes, w_gate, w_up,
+                 w_down):
+    """The routed experts' output per token, f32 ``(T, d)``, on a buffer of
+    ``len(assign)`` rows: the tokens gathered into expert order, three
+    grouped matmuls, and each token's weighted sum of its rows."""
+    with jax.named_scope("dispatch"):
+        rows = _dispatch(xt, assign, slot)
+    with jax.named_scope("experts"):
+        gate = jax.nn.silu(grouped_matmul(rows, w_gate, group_sizes))
+        up = grouped_matmul(rows, w_up, group_sizes)
+        out = grouped_matmul((gate * up).astype(xt.dtype), w_down,
+                             group_sizes)
+    with jax.named_scope("combine"):
+        return _combine(out.astype(xt.dtype), weights, assign, slot)
+
+
+# A layer that holds a share of the experts computes only the assignments
+# routed to them, but how many those are varies per worker and layer.  It
+# runs on the smallest of the buffers of T, 2T, ..., T * min(K, G) rows
+# that holds its routed rows: the last holds any routing, so nothing is
+# dropped.  The buffer is chosen per worker by ``lax.switch``, which under
+# the worker ``vmap`` would become a select computing every buffer; so the
+# forward and the backward each run the workers in turn, where the switch
+# stays a branch.  The backward recomputes the rows in its buffer from the
+# layer's inputs, so its residuals have one shape whatever the buffer.
+
+def _bucket(group_sizes, T: int, n: int):
+    """The index ``j`` of the buffer of ``(j + 1) * T`` rows, the smallest
+    of ``n`` that holds the ``group_sizes.sum()`` routed rows."""
+    return jnp.clip((group_sizes.sum() + T - 1) // T - 1, 0, n - 1)
+
+
+def _in_buckets(run, order, slot, group_sizes):
+    """``run(assign)``, ``assign`` the first ``(j + 1) * T`` of the
+    expert-sorted ``order``, for the bucket ``j`` the routing needs."""
+    T, K = slot.shape
+    n = min(K, group_sizes.shape[0])
+    branches = [lambda o, rows=(j + 1) * T: run(o[:rows]) for j in range(n)]
+    return jax.lax.switch(_bucket(group_sizes, T, n), branches, order)
+
+
+@_lanes_in_turn
+def _bucketed_fwd(xt, weights, order, slot, group_sizes, *experts):
+    run = lambda assign: _expert_rows(xt, weights, assign, slot, group_sizes,
+                                      *experts)
+    return _in_buckets(run, order, slot, group_sizes)
+
+
+@_lanes_in_turn
+def _bucketed_bwd(xt, weights, order, slot, group_sizes, *experts_and_g):
+    *experts, g = experts_and_g
+
+    def run(assign):
+        body = lambda xt, weights, *experts: _expert_rows(
+            xt, weights, assign, slot, group_sizes, *experts)
+        return jax.vjp(body, xt, weights, *experts)[1](g)
+    return _in_buckets(run, order, slot, group_sizes)
+
+
+@jax.custom_vjp
+def _bucketed_rows(xt, weights, order, slot, group_sizes, w_gate, w_up,
+                   w_down):
+    """:func:`_expert_rows` on the smallest buffer of whole multiples of
+    the ``T`` tokens that holds the routed rows (``order``: all ``T * K``
+    assignments, expert-sorted)."""
+    return _bucketed_fwd(xt, weights, order, slot, group_sizes, w_gate,
+                         w_up, w_down)
+
+
+def _bucketed_rows_fwd(*args):
+    return _bucketed_rows(*args), args
+
+
+def _bucketed_rows_bwd(res, g):
+    d_xt, d_weights, d_gate, d_up, d_down = _bucketed_bwd(*res, g)
+    return d_xt, d_weights, None, None, None, d_gate, d_up, d_down
+
+
+_bucketed_rows.defvjp(_bucketed_rows_fwd, _bucketed_rows_bwd)
+
+
 def moe_apply(params, cfg, x):
     """Routed experts (top-k of the router's logits, softmax over the k
     chosen) + optional shared experts, with no capacity: every assignment
@@ -768,13 +859,16 @@ def moe_apply(params, cfg, x):
     them: assignments sorted by held expert (the rest after them), the
     tokens gathered into that order, three grouped matmuls over the rows
     and the weighted sum back per token.  What the experts it does not
-    hold would add is left out.  The buffer has ``T * min(K, G)`` rows,
-    enough for any routing of ``T`` tokens to ``G`` held experts.
+    hold would add is left out.  A layer that holds every expert computes
+    all ``T * K`` assignments; one that holds a share computes on the
+    smallest buffer of ``T, 2T, ..., T * min(K, G)`` rows that holds the
+    rows routed to it, the last enough for any routing.
 
     Returns ``(y, aux)``: ``aux["moe_aux"]`` is the switch-style
     load-balance loss over all ``n_experts``; ``expert_rows_max`` and
     ``expert_rows_sum`` are the largest and the total number of rows the
-    held experts computed.
+    held experts computed, ``expert_rows_buffer`` the rows of the buffer
+    they were computed on.
     """
     B, L, d = x.shape
     E, K = cfg.n_experts, cfg.top_k
@@ -783,6 +877,7 @@ def moe_apply(params, cfg, x):
     T = B * L
     N = T * min(K, G)
     xt = x.reshape(T, d)
+    experts = (params["w_gate"], params["w_up"], params["w_down"])
 
     with jax.named_scope("moe"):
         with jax.named_scope("router"):
@@ -803,25 +898,24 @@ def moe_apply(params, cfg, x):
             rank = jnp.zeros((T * K,), jnp.int32).at[order].set(
                 jnp.arange(T * K, dtype=jnp.int32), unique_indices=True)
             slot = jnp.where(key < G, rank, N).reshape(T, K)
-            assign = order[:N]
-            rows = _dispatch(xt, assign, slot)                 # (N, d)
 
-        with jax.named_scope("experts"):
-            gate = jax.nn.silu(grouped_matmul(rows, params["w_gate"],
-                                              group_sizes))
-            up = grouped_matmul(rows, params["w_up"], group_sizes)
-            out = grouped_matmul((gate * up).astype(x.dtype),
-                                 params["w_down"], group_sizes)
-
-        with jax.named_scope("combine"):
-            y = _combine(out.astype(x.dtype), weights, assign, slot)
+        if G == E:
+            y = _expert_rows(xt, weights, order[:N], slot, group_sizes,
+                             *experts)
+            buffer = jnp.asarray(N, f32)
+        else:
+            y = _bucketed_rows(xt, weights, order, slot, group_sizes,
+                               *experts)
+            buffer = (T * (_bucket(group_sizes, T, min(K, G)) + 1)
+                      ).astype(f32)
 
     if cfg.n_shared_experts > 0:
         y = y + mlp_apply(params["shared"], "swiglu", x).reshape(T, d)
 
     stats = {"moe_aux": aux,
              "expert_rows_max": group_sizes.max().astype(f32),
-             "expert_rows_sum": group_sizes.sum().astype(f32)}
+             "expert_rows_sum": group_sizes.sum().astype(f32),
+             "expert_rows_buffer": buffer}
     return y.reshape(B, L, d).astype(x.dtype), stats
 
 

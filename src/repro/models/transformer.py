@@ -171,7 +171,8 @@ def init_cache(cfg: ModelConfig, batch: int, max_seq: int):
 def _no_aux():
     """A layer's MoE statistics where it has no routed experts."""
     z = jnp.zeros((), f32)
-    return {"moe_aux": z, "expert_rows_max": z, "expert_rows_sum": z}
+    return {"moe_aux": z, "expert_rows_max": z, "expert_rows_sum": z,
+            "expert_rows_buffer": z}
 
 
 def _add_aux(a, b):
@@ -180,7 +181,9 @@ def _add_aux(a, b):
     return {"moe_aux": a["moe_aux"] + b["moe_aux"],
             "expert_rows_max": jnp.maximum(a["expert_rows_max"],
                                            b["expert_rows_max"]),
-            "expert_rows_sum": a["expert_rows_sum"] + b["expert_rows_sum"]}
+            "expert_rows_sum": a["expert_rows_sum"] + b["expert_rows_sum"],
+            "expert_rows_buffer": (a["expert_rows_buffer"]
+                                   + b["expert_rows_buffer"])}
 
 
 def _residual(cfg, x, out):
@@ -271,7 +274,8 @@ def forward(params, cfg: ModelConfig, inputs, *, positions=None,
     the layers' summed load-balance loss and, with routed experts,
     ``expert_rows_max`` / ``expert_rows_mean``, the largest and the mean
     number of assignments a held expert computed, over layers and held
-    experts.
+    experts, and ``expert_buffer_fill``, the routed rows over the rows of
+    the buffers they were computed on, summed over layers.
     """
     if mode == "decode" and cache is None:
         raise ValueError("decode needs a cache")
@@ -390,6 +394,8 @@ def forward(params, cfg: ModelConfig, inputs, *, positions=None,
         aux["expert_rows_max"] = aux_total["expert_rows_max"]
         aux["expert_rows_mean"] = aux_total["expert_rows_sum"] / (
             n_moe * len(cfg.held_experts))
+        aux["expert_buffer_fill"] = (aux_total["expert_rows_sum"]
+                                     / aux_total["expert_rows_buffer"])
     return logits, new_cache, aux
 
 
@@ -420,9 +426,10 @@ def cross_entropy(logits, targets, mask=None):
 
 def loss_and_load(params, cfg: ModelConfig, batch, *, positions=None):
     """Next-token LM loss and the held routed experts' load: the row
-    counts of :func:`forward` (``expert_rows_max``, ``expert_rows_mean``),
-    ``{}`` without routed experts.  batch: {"tokens": (B, L)} or, for stub
-    frontends, {"embeds": (B, L, d), "labels": (B, L)}."""
+    counts of :func:`forward` (``expert_rows_max``, ``expert_rows_mean``,
+    ``expert_buffer_fill``), ``{}`` without routed experts.  batch:
+    {"tokens": (B, L)} or, for stub frontends, {"embeds": (B, L, d),
+    "labels": (B, L)}."""
     if cfg.embed_stub:
         inputs, labels = batch["embeds"], batch["labels"]
     else:

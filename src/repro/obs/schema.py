@@ -188,6 +188,9 @@ METRICS: Dict[str, MetricSpec] = _spec_table([
     MetricSpec("expert_rows_mean", "float32", PER_WORKER, "model",
                "mean number of assignments per held routed expert and "
                "layer in this step's forward"),
+    MetricSpec("expert_buffer_fill", "float32", PER_WORKER, "model",
+               "routed rows over the rows of the held experts' buffers "
+               "they were computed on, summed over layers, in (0, 1]"),
     MetricSpec("escape_on", "float32", SCALAR, "trainer",
                "sgd_escape perturbation gate (1 = noise injected)"),
     MetricSpec("attack_level", "float32", SCALAR, "attack",

@@ -78,14 +78,15 @@ def test_sharded_safeguard_matches_local():
 
 
 _MOE_SCRIPT = r"""
-import os, re, sys
+import dataclasses, os, re, sys
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, numpy as np
 import repro.configs as C
 from repro.launch import train as train_lib
 
-data_n, model_n, m = (int(a) for a in sys.argv[1:4])
-cfg = C.get_smoke("granite-moe-3b-a800m")
+data_n, model_n, m, held = (int(a) for a in sys.argv[1:5])
+cfg = dataclasses.replace(C.get_smoke("granite-moe-3b-a800m"),
+                          n_held_experts=held)
 args = train_lib.parse_args([
     "--arch", "granite-moe-3b-a800m", "--workers", str(m), "--byz", "1",
     "--batch", str(2 * m), "--seq", "32", "--attack", "sign_flip",
@@ -109,15 +110,29 @@ assert (good == good_ref).all(), (good, good_ref)
 assert not good[-1][0] and good[:, 1:].all()
 np.testing.assert_allclose(loss, loss_ref, rtol=1e-5)
 
-# the grouped matmuls' per-worker loops, per chip: over the chip's own
-# m / data workers, not all m
+# the layer's per-worker loops (the grouped matmuls', or the held share's
+# whole routed part), per chip: over the chip's own m / data workers, not
+# all m
 txt = tr.step_fn.lower(tr.state, next(tr.data_iter)).compile().as_text()
 trips = [int(n) for n in re.findall(
-    r'op_name="[^"]*/moe/experts/[^"]*while"[^\n]*'
+    r'op_name="[^"]*/moe/[^"]*while"[^\n]*'
     r'known_trip_count":\{"n":"(\d+)"', txt)]
 assert trips and set(trips) == {m // data_n}, trips
+# a held share chooses each worker's buffer by a branch
+assert ("conditional(" in txt) == (0 < held < cfg.n_experts)
 print("MOE_MESH_OK")
 """
+
+
+def _moe_mesh_step(data_n, model_n, m, held):
+    env = dict(os.environ)
+    env["PYTHONPATH"] = "src"
+    out = subprocess.run([sys.executable, "-c", _MOE_SCRIPT, str(data_n),
+                          str(model_n), str(m), str(held)], env=env,
+                         capture_output=True, text=True, timeout=600,
+                         cwd=os.path.dirname(os.path.dirname(
+                             os.path.abspath(__file__))))
+    assert "MOE_MESH_OK" in out.stdout, (out.stdout, out.stderr[-4000:])
 
 
 @pytest.mark.parametrize("data_n,model_n,m", [(4, 1, 8), (2, 2, 4)])
@@ -127,14 +142,17 @@ def test_held_expert_step_on_a_mesh_matches_one_device(data_n, model_n, m):
     losses and the same evictions in 12 steps (the sign-flipper evicted,
     no honest worker), and each chip runs the grouped matmuls of its own
     two workers only."""
-    env = dict(os.environ)
-    env["PYTHONPATH"] = "src"
-    out = subprocess.run([sys.executable, "-c", _MOE_SCRIPT, str(data_n),
-                          str(model_n), str(m)], env=env,
-                         capture_output=True, text=True, timeout=600,
-                         cwd=os.path.dirname(os.path.dirname(
-                             os.path.abspath(__file__))))
-    assert "MOE_MESH_OK" in out.stdout, (out.stdout, out.stderr[-4000:])
+    _moe_mesh_step(data_n, model_n, m, held=0)
+
+
+@pytest.mark.parametrize("data_n,model_n,m", [(4, 1, 8), (2, 2, 4)])
+def test_bucketed_held_share_step_on_a_mesh_matches_one_device(
+        data_n, model_n, m):
+    """The same with the layers holding 2 of the 4 experts, so each worker
+    chooses its buffer (T or 2T rows) by a branch: on the mesh the step
+    still matches one device, and each chip runs the routed part of its
+    own workers only."""
+    _moe_mesh_step(data_n, model_n, m, held=2)
 
 
 @pytest.mark.slow

@@ -439,3 +439,96 @@ def test_moe_drops_nothing_when_every_token_picks_one_expert(rng):
     np.testing.assert_allclose(np.asarray(y),
                                np.asarray(_moe_dense(p, share, x)),
                                rtol=1e-5, atol=1e-6)
+
+
+def _held_share_cfg(**kw):
+    """4 of 8 experts held, top 3: buffers of T, 2T and 3T rows."""
+    return _moe_cfg(n_experts=8, top_k=3, first_held_expert=2,
+                    n_held_experts=4, **kw)
+
+
+def _steered(rng, cfg, lean):
+    """Layer weights and one (2, 16)-token batch per worker whose feature
+    0 is ``lean[w]`` and moves the logits of held experts 3-5 by it: -10
+    leaves held expert 2 alone to take assignments (the smallest buffer),
+    0 the plain routing, +10 sends every token's top 3 to held experts
+    (the full buffer)."""
+    params = L.moe_init(rng, cfg)
+    params["router"] = params["router"].at[0].set(0.0).at[0, 3:6].set(1.0)
+    x = jax.random.normal(jax.random.fold_in(rng, 1),
+                          (len(lean), 2, 16, cfg.d_model))
+    return params, x.at[..., 0].set(jnp.asarray(lean, f32)[:, None, None])
+
+
+@pytest.mark.parametrize("lean,rows", [
+    ((-10.0, -10.0, -10.0), (32, 32, 32)),
+    ((0.0, 0.0, 0.0), (64, 64, 64)),
+    ((10.0, 10.0, 10.0), (96, 96, 96)),
+    ((-10.0, 0.0, 10.0), (32, 64, 96)),
+], ids=["smallest", "middle", "full", "each_its_own"])
+def test_moe_bucketed_layer_matches_dense_under_worker_vmap(rng, lean, rows):
+    """A layer holding a share runs each worker on the smallest buffer of
+    T = 32, 64 or 96 rows that holds its routed rows, and under the
+    worker ``vmap`` its values and its gradients (router, experts and
+    ``x``) still equal the dense loop.  The full buffer computes all 3T
+    assignments: no routing drops one."""
+    cfg = _held_share_cfg()
+    params, x = _steered(rng, cfg, lean)
+    y, st = jax.vmap(lambda xi: L.moe_apply(params, cfg, xi))(x)
+    live = np.asarray(st["expert_rows_sum"])
+    np.testing.assert_array_equal(np.asarray(st["expert_rows_buffer"]), rows)
+    assert ((live > np.asarray(rows) - 32) & (live <= np.asarray(rows))).all()
+    if rows[0] == 96:
+        assert (live == 96).all()
+    np.testing.assert_allclose(
+        np.asarray(st["expert_rows_sum"] / st["expert_rows_buffer"]),
+        live / np.asarray(rows, np.float32), rtol=1e-6)
+    dense = jax.vmap(lambda xi: _moe_dense(params, cfg, xi))(x)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(dense),
+                               rtol=1e-5, atol=1e-6)
+    sq = lambda f: lambda p, x: (f(p, x) ** 2).sum()
+    grads = lambda f: jax.vmap(jax.grad(sq(f), argnums=(0, 1)),
+                               in_axes=(None, 0))(params, x)
+    g_layer = grads(lambda p, x: L.moe_apply(p, cfg, x)[0])
+    g_dense = grads(lambda p, x: _moe_dense(p, cfg, x))
+    for a, b in zip(jax.tree.leaves(g_layer), jax.tree.leaves(g_dense)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=1e-6)
+
+
+def _conds(jaxpr):
+    """The ``cond`` equations of a jaxpr and of every jaxpr inside it."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "cond":
+            out.append(eqn)
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    out += _conds(inner)
+    return out
+
+
+def _moe_grad_jaxpr(cfg, rng):
+    params, x = _steered(rng, cfg, (0.0, 0.0, 0.0))
+    loss = lambda p, xi: (L.moe_apply(p, cfg, xi)[0] ** 2).sum()
+    return jax.make_jaxpr(jax.vmap(jax.value_and_grad(loss, argnums=(0, 1)),
+                                   in_axes=(None, 0)))(params, x).jaxpr
+
+
+def test_moe_bucket_choice_is_a_branch_per_worker(rng):
+    """Under the worker ``vmap`` the layer's forward and backward each
+    choose their buffer with a ``cond`` of one branch per buffer (T, 2T,
+    3T): each worker runs one branch, not a select of all three."""
+    conds = _conds(_moe_grad_jaxpr(_held_share_cfg(), rng))
+    assert len(conds) >= 2
+    assert all(len(c.params["branches"]) == 3 for c in conds)
+
+
+def test_moe_layer_holding_every_expert_has_no_branch(rng):
+    """A layer that holds every expert computes all T * K assignments on
+    one buffer, with no ``cond``."""
+    cfg = _moe_cfg(n_experts=8, top_k=3)
+    assert len(cfg.held_experts) == cfg.n_experts
+    assert _conds(_moe_grad_jaxpr(cfg, rng)) == []

@@ -82,6 +82,33 @@ def test_schema_info_surface_and_per_bucket():
         validate_info({"bucket_good": jnp.ones((3,), bool)}, M)
 
 
+@pytest.mark.parametrize("held", [0, 2])
+def test_expert_buffer_fill_is_a_per_worker_share(held):
+    """``expert_buffer_fill`` is registered per worker, from the model;
+    the held-expert load of Granite-MoE's smoke model under a worker
+    ``vmap`` carries it, and it lies in (0, 1]: 1 where the layers hold
+    every expert and compute all T * K assignments."""
+    import dataclasses
+    import repro.configs as C
+    from repro.models import transformer as T
+    spec = schema_lib.spec_of("expert_buffer_fill")
+    assert (spec.shape_class, spec.source) == (schema_lib.PER_WORKER,
+                                               "model")
+    cfg = dataclasses.replace(C.get_smoke("granite-moe-3b-a800m"),
+                              n_held_experts=held)
+    params = T.init_params(cfg, jax.random.PRNGKey(0))
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (M, 2, 32), 0,
+                                cfg.vocab_size)
+    _, load = jax.vmap(lambda t: T.loss_and_load(params, cfg,
+                                                 {"tokens": t}))(tokens)
+    validate_metrics(load, M)
+    fill = np.asarray(load["expert_buffer_fill"])
+    assert fill.shape == (M,)
+    assert ((fill > 0) & (fill <= 1)).all()
+    if not held:
+        np.testing.assert_array_equal(fill, 1.0)
+
+
 def test_register_metric_refuses_silent_redefinition():
     spec = MetricSpec("test_only_metric", "float32", schema_lib.SCALAR,
                       "probe")
